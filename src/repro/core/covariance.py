@@ -18,6 +18,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Array = jax.Array
 
@@ -65,17 +66,28 @@ def sqrt_and_inv_sqrt(moment: Array, count: Array | float, *, damping: float = 1
     Returns ``(S, S_inv)`` with ``S = Sigma^{1/2}``. Damping regularizes
     directions never excited by the calibration set; the paper's whitening is
     otherwise singular for rank-deficient activation covariances.
+
+    A numpy ``moment`` is solved on the host with numpy (see ``array_module``).
     """
+    xp = array_module(moment)
     n = moment.shape[0]
-    cov = moment / jnp.maximum(jnp.asarray(count, jnp.float32), 1.0)
+    cov = moment / xp.maximum(xp.asarray(count, xp.float32), 1.0)
     # Scale-aware damping: relative to mean diagonal energy.
-    lam = damping * (jnp.trace(cov) / n + 1e-30)
-    cov = cov + lam * jnp.eye(n, dtype=cov.dtype)
-    w, q = jnp.linalg.eigh(cov)
-    w = jnp.maximum(w, 0.0) + lam
-    s = (q * jnp.sqrt(w)) @ q.T
-    s_inv = (q * (1.0 / jnp.sqrt(w))) @ q.T
+    lam = damping * (xp.trace(cov) / n + 1e-30)
+    cov = cov + lam * xp.eye(n, dtype=cov.dtype)
+    w, q = xp.linalg.eigh(cov)
+    w = xp.maximum(w, 0.0) + lam
+    s = (q * xp.sqrt(w)) @ q.T
+    s_inv = (q * (1.0 / xp.sqrt(w))) @ q.T
     return s, s_inv
+
+
+def array_module(x):
+    """numpy for host arrays, else jax.numpy. DataSVD runs once at set-up,
+    and for a TPU ``jnp.linalg.eigh``/``svd`` compile for minutes per shape
+    (566 s for one 3072 x 3072 eigh for a v5e) while the host LAPACK solves
+    the same problem in seconds; callers hand numpy in to get that."""
+    return np if isinstance(x, np.ndarray) else jnp
 
 
 def collect_layer_moments(apply_fn, params, batches, layer_taps) -> Dict[str, CovarianceState]:

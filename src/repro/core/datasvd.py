@@ -20,7 +20,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.covariance import sqrt_and_inv_sqrt
+from repro.core.covariance import array_module, sqrt_and_inv_sqrt
 
 Array = jax.Array
 
@@ -49,14 +49,16 @@ def datasvd_factors(
     max_rank: Optional[int] = None,
     damping: float = 1e-6,
 ) -> Factors:
-    """Whitened SVD factorization of ``w`` against activation moment."""
-    w = w.astype(jnp.float32)
+    """Whitened SVD factorization of ``w`` against activation moment; on the
+    host in numpy when both are numpy arrays (``array_module``)."""
+    xp = array_module(moment)
+    w = w.astype(xp.float32)
     s, s_inv = sqrt_and_inv_sqrt(moment, count, damping=damping)
-    p, lam, qt = jnp.linalg.svd(w @ s, full_matrices=False)
+    p, lam, qt = xp.linalg.svd(w @ s, full_matrices=False)
     q = qt.T
     if max_rank is not None:
         p, lam, q = p[:, :max_rank], lam[:max_rank], q[:, :max_rank]
-    sqrt_lam = jnp.sqrt(lam)
+    sqrt_lam = xp.sqrt(lam)
     u = p * sqrt_lam[None, :]
     v = (s_inv @ q) * sqrt_lam[None, :]
     return Factors(u=u, v=v)
@@ -64,12 +66,13 @@ def datasvd_factors(
 
 def plain_svd_factors(w: Array, *, max_rank: Optional[int] = None) -> Factors:
     """Weight-only SVD baseline (no activation weighting)."""
-    w = w.astype(jnp.float32)
-    p, lam, qt = jnp.linalg.svd(w, full_matrices=False)
+    xp = array_module(w)
+    w = w.astype(xp.float32)
+    p, lam, qt = xp.linalg.svd(w, full_matrices=False)
     q = qt.T
     if max_rank is not None:
         p, lam, q = p[:, :max_rank], lam[:max_rank], q[:, :max_rank]
-    sqrt_lam = jnp.sqrt(lam)
+    sqrt_lam = xp.sqrt(lam)
     return Factors(u=p * sqrt_lam[None, :], v=q * sqrt_lam[None, :])
 
 

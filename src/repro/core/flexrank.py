@@ -148,6 +148,7 @@ def decompose(
     curve[r-1] = probe error of keeping rank r uniformly (DP input).
 
     Falls back to plain SVD per leaf when no moment was recorded for it.
+    The factorizations run on the host (numpy LAPACK), whatever the backend.
     """
     import copy
     infos = group_infos(cfg)
@@ -170,12 +171,14 @@ def decompose(
             ent = group_moments.get(tuple(scan_idx))
             w_slice = w[idx]                            # (n, m): y = x @ w
             w_paper = w_slice.T                         # (m, n): y = W x
+            # numpy operands: the factorization runs on the host
             if ent is not None:
-                f = datasvd.datasvd_factors(jnp.asarray(w_paper),
-                                            jnp.asarray(ent[0]), ent[1],
-                                            max_rank=r_full, damping=damping)
+                f = datasvd.datasvd_factors(w_paper,
+                                            np.asarray(ent[0], np.float32),
+                                            ent[1], max_rank=r_full,
+                                            damping=damping)
             else:
-                f = datasvd.plain_svd_factors(jnp.asarray(w_paper), max_rank=r_full)
+                f = datasvd.plain_svd_factors(w_paper, max_rank=r_full)
             u_np, v_np = np.asarray(f.u), np.asarray(f.v)
             rr = u_np.shape[1]
             u_out[idx][:, :rr] = u_np

@@ -21,6 +21,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import numpy as np
+import scipy.linalg
 import jax
 import jax.numpy as jnp
 
@@ -45,23 +46,14 @@ class GarFactors(NamedTuple):
 def _pivot_rows(u: np.ndarray) -> np.ndarray:
     """Greedy partial-pivoting row selection: r rows making U[rows] well-conditioned.
 
-    Gaussian elimination with row pivoting on a working copy; equivalent to
-    the permutation of an LU(P) factorization of ``U`` restricted to its first
-    r pivots. O(m r^2).
+    The row permutation of an LU factorization with partial pivoting (LAPACK
+    ``getrf``), whose first r rows are the pivots. O(m r^2).
     """
-    m, r = u.shape
-    work = u.astype(np.float64).copy()
+    m = u.shape[0]
+    _, swaps = scipy.linalg.lu_factor(u.astype(np.float64), check_finite=False)
     rows = np.arange(m)
-    for j in range(r):
-        pivot = j + int(np.argmax(np.abs(work[j:, j])))
-        if pivot != j:
-            work[[j, pivot]] = work[[pivot, j]]
-            rows[[j, pivot]] = rows[[pivot, j]]
-        piv = work[j, j]
-        if abs(piv) < 1e-12:
-            continue  # rank-deficient direction; keep going, damped inverse later
-        below = work[j + 1:, j] / piv
-        work[j + 1:] -= np.outer(below, work[j])
+    for j, p in enumerate(swaps):       # LAPACK's row interchanges, in order
+        rows[[j, p]] = rows[[p, j]]
     return rows
 
 

@@ -1,9 +1,12 @@
 """jit'd public wrappers around the Pallas kernels: padding to tile-aligned
 shapes, (B, S, ...) <-> kernel layout reshapes, output permutation for GAR.
 
-``use_pallas`` dispatch: True on TPU (real kernels), 'interpret' for CPU
-validation, False -> pure-jnp oracle path (identical numerics guaranteed by
-tests/test_kernels.py sweeps).
+``use_pallas`` dispatch: True -> the real kernels (what the serving engine
+picks on a TPU), 'interpret' -> the same kernels through the Pallas
+interpreter (CPU validation, tests only), False -> the pure-jnp oracle
+(identical numerics guaranteed by tests/test_kernels.py sweeps). The
+wrappers never swap an oracle in for a kernel the caller asked for: a
+request the kernel cannot serve raises.
 """
 from __future__ import annotations
 
@@ -37,6 +40,13 @@ def _pad_to(x, multiple, axis):
     width = [(0, 0)] * x.ndim
     width[axis] = (0, pad)
     return jnp.pad(x, width), size
+
+
+def _no_window(window):
+    if window is not None:
+        raise ValueError("the Pallas paged-attention kernels have no "
+                         "sliding window; serve windowed layers with "
+                         "use_pallas=False")
 
 
 def gar_forward(x: jax.Array, v_tilde: jax.Array, u_hat: jax.Array,
@@ -91,10 +101,11 @@ def paged_attention_forward(q, k_pool, v_pool, block_tables, context_lens, *,
     block_tables: (B, MB); context_lens: (B,). Returns (B, Hq, D).
 
     ``window`` (sliding-window lookback) is only supported on the oracle
-    path — the serving engine routes local-window layers there.
+    path: callers serving a windowed layer pass ``use_pallas=False``.
     """
     run, interp = _mode(use_pallas)
-    if run and window is None:
+    if run:
+        _no_window(window)
         return paged_attention(q, k_pool, v_pool,
                                jnp.asarray(block_tables, jnp.int32),
                                jnp.asarray(context_lens, jnp.int32),
@@ -112,10 +123,11 @@ def paged_prefill_attention_forward(q, k_pool, v_pool, block_tables, slot_ids,
     block_tables: (B, MB); slot_ids/context_lens: (T,). Returns (T, Hq, D).
 
     ``window`` (sliding-window lookback) is only supported on the oracle
-    path — the serving engine routes local-window layers there.
+    path: callers serving a windowed layer pass ``use_pallas=False``.
     """
     run, interp = _mode(use_pallas)
-    if run and window is None:
+    if run:
+        _no_window(window)
         return paged_prefill_attention(q, k_pool, v_pool,
                                        jnp.asarray(block_tables, jnp.int32),
                                        jnp.asarray(slot_ids, jnp.int32),
@@ -126,6 +138,32 @@ def paged_prefill_attention_forward(q, k_pool, v_pool, block_tables, slot_ids,
                                            softcap=softcap, window=window)
 
 
+def topk_threshold(z, top_k):
+    """Per-row top-k cutoff, bitwise ``ref.topk_threshold_ref`` without
+    its sort: a full-vocab sort costs the TPU compiler about 17 s per
+    program (gpt2-small vocab, v5e), and every sampled step shape is a
+    program. Instead the k-th largest value is built bit by bit: on an
+    order-preserving uint32 image of the float32 values, the largest key
+    ``t`` with ``count(key >= t) >= k`` is found in 32 compare-and-count
+    passes over the row, and is the k-th largest key itself.
+
+    z: (S, V) float32; top_k: (S,) int32, 0 = no truncation (-inf)."""
+    bits = jax.lax.bitcast_convert_type(z.astype(jnp.float32), jnp.uint32)
+    sign = jnp.uint32(0x80000000)
+    key = jnp.where(bits >= sign, ~bits, bits | sign)     # monotone in z
+    k = jnp.clip(jnp.asarray(top_k, jnp.int32), 1, z.shape[-1])
+
+    def set_bit(i, t):
+        cand = t | (sign >> i.astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[:, None], axis=-1) >= k
+        return jnp.where(enough, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, set_bit, jnp.zeros(z.shape[:1], jnp.uint32))
+    thr = jax.lax.bitcast_convert_type(jnp.where(t >= sign, t ^ sign, ~t),
+                                       jnp.float32)
+    return jnp.where(jnp.asarray(top_k) > 0, thr, -jnp.inf)
+
+
 def topk_mask_sample_forward(logits, temperature, top_k, u, *,
                              return_probs: bool = False, use_pallas=False):
     """Fused temperature/top-k warp + one categorical draw per logits row
@@ -133,24 +171,24 @@ def topk_mask_sample_forward(logits, temperature, top_k, u, *,
 
     logits: (S, V); temperature: (S,) — ``<= 0`` means greedy argmax;
     top_k: (S,) int32 (0 = no truncation) or ``None`` when no row in the
-    batch truncates (skips the threshold sort entirely — the common greedy
+    batch truncates (skips the threshold entirely — the common greedy
     / pure-temperature serving case); u: (S,) keyed uniforms in [0, 1).
     Returns ``tokens (S,) int32`` (plus the warped ``probs (S, V)`` when
     ``return_probs`` — the speculative draft phase keeps it as ``q``).
 
     The per-row top-k *threshold* (k-th largest scaled logit) needs global
-    ranking, so it is computed here with one device sort and handed to the
+    ranking, so it is computed here (``topk_threshold``) and handed to the
     kernel / oracle as a cutoff value; the streaming warp + inverse-CDF
     draw is what the Pallas kernel fuses.
     """
     temperature = jnp.asarray(temperature, jnp.float32)
     u = jnp.asarray(u, jnp.float32)
     if top_k is None:
-        threshold = None                       # no row truncates: no sort,
-    else:                                      # no masking pass
+        threshold = None                       # no row truncates: no
+    else:                                      # threshold, no masking pass
         z = (logits.astype(jnp.float32)
              / jnp.maximum(temperature, 1e-30)[:, None])
-        threshold = ref.topk_threshold_ref(z, jnp.asarray(top_k, jnp.int32))
+        threshold = topk_threshold(z, top_k)
     run, interp = _mode(use_pallas)
     if run:
         thr = (threshold if threshold is not None

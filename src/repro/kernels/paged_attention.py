@@ -18,9 +18,11 @@ its block-table row; per-token ``context_lens`` (= position + 1) express
 intra-chunk causality, because the chunk's own K/V is scattered into the
 pool before the kernel runs.
 
-Head/lane tiling note: shapes here are serving-sized (Hq x D panels); on real
-TPUs Hq*G and D should be padded to the (8, 128) tile by the ops.py wrapper.
-Tests validate via interpret mode against ``ref.paged_attention_ref``.
+Tiling: the q block is (1, Hq, D) and each K/V block (1, BS, Hkv, D); their
+last two dims span the array axes, which Mosaic accepts at any size, so no
+padding is needed (``tests/test_tpu_compile.py`` compiles both kernels for a
+v5e at gpt2-small widths). Tests validate numerics via interpret mode
+against ``ref.paged_attention_ref``.
 """
 from __future__ import annotations
 
@@ -118,7 +120,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     (flash) softmax over blocks with float32 running (max, denom, acc)
     scratch. Returns (B, Hq, D) in ``q``'s dtype. Prefer calling through
     ``ops.paged_attention_forward`` — it owns the ref/Pallas/interpret
-    dispatch and the sliding-window oracle fallback."""
+    dispatch."""
     b, hq, d = q.shape
     _, bs, hkv, _ = k_pool.shape
     mb = block_tables.shape[1]

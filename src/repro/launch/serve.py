@@ -26,6 +26,7 @@ import numpy as np
 from repro import obs
 from repro.configs import get_config
 from repro.data import make_source
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import build_flexrank_state
 from repro.models import common as cm
 from repro.models import transformer as tfm
@@ -197,6 +198,7 @@ def main(argv=None):
                          "state); empty = no bundles, the firing still "
                          "traces and counts")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     rng = np.random.default_rng(args.seed)

@@ -21,7 +21,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
@@ -29,8 +28,9 @@ from repro.core import flexrank as FR
 from repro.data import make_source, calibration_batches
 from repro.distributed import (PreemptionGuard, StragglerMonitor, elastic_remesh,
                                mesh_context, param_shardings)
-from repro.distributed.sharding import batch_sharding
+from repro.distributed.sharding import batch_sharding, replicated
 from repro.launch import specs as SP
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import single_device_mesh
 from repro.models import common as cm
 from repro.models import transformer as tfm
@@ -44,6 +44,27 @@ def build_flexrank_state(cfg, dense_params, source, *, calib_batches=8):
     fact_params, curves = FR.decompose(dense_params, cfg, moments)
     table, infos = FR.build_table(cfg, curves)
     return fact_params, table, infos
+
+
+def state_shardings(mesh, axes, params, opt_state):
+    """Where the training state lives on ``mesh``: parameters by their
+    logical ``axes`` (``param_shardings``), every optimizer subtree laid
+    out like the parameters (the moments) likewise, and everything else
+    (step counters, empty placeholders) replicated."""
+    pshard = param_shardings(mesh, axes, params)
+    rep = replicated(mesh)
+    pdef = jax.tree.structure(params)
+
+    def like_params(t):
+        return jax.tree.structure(t) == pdef
+
+    def moments(t):
+        return jax.tree.map(lambda a, p, sh: sh if a.shape == p.shape else rep,
+                            t, params, pshard)
+
+    oshard = jax.tree.map(lambda t: moments(t) if like_params(t) else rep,
+                          opt_state, is_leaf=like_params)
+    return pshard, oshard
 
 
 def main(argv=None):
@@ -68,6 +89,7 @@ def main(argv=None):
                          "on 1 device; compresses DP all-reduce on a mesh)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.mesh_shape:
@@ -107,11 +129,16 @@ def main(argv=None):
         # ------- restart path -------
         start_step = 0
         if mgr and mgr.latest_step() is not None:
-            pshard = param_shardings(mesh, cm.axes_tree(
-                FR.factorized_spec(cfg) if infos else spec))
-            placer = lambda k, a: jax.device_put(jnp.asarray(a))
-            (params, opt_state), start_step = mgr.restore((params, opt_state), placer=placer)
+            # host arrays: the placement below shards them like a fresh start
+            (params, opt_state), start_step = mgr.restore(
+                (params, opt_state), placer=lambda k, a: a)
             print(f"[restart] resumed from step {start_step}")
+
+        # ------- placement on the mesh -------
+        axes = cm.axes_tree(FR.factorized_spec(cfg) if infos else spec)
+        shardings = state_shardings(mesh, axes, params, opt_state)
+        params, opt_state = jax.device_put((params, opt_state), shardings)
+        data_sharding = batch_sharding(mesh)
 
         # ------- step fn -------
         if args.optimizer == "muon":
@@ -121,8 +148,10 @@ def main(argv=None):
             apply_fn = lambda p, g, st: adamw.apply_updates(p, g, st, opt_cfg)
 
         if args.mode == "flexrank_kd":
+            teacher = jax.device_put(dense_params, param_shardings(
+                mesh, cm.axes_tree(spec), dense_params))
             loss_fn = FR.make_consolidation_loss(cfg, infos, FR.table_device(table),
-                                                 dense_params)
+                                                 teacher)
 
             @jax.jit
             def step_fn(params, opt_state, batch, rng):
@@ -150,7 +179,8 @@ def main(argv=None):
         # ------- loop -------
         losses = []
         for step in range(start_step, args.steps):
-            batch = {"tokens": jnp.asarray(source.batch_at(step)["tokens"])}
+            batch = {"tokens": jax.device_put(source.batch_at(step)["tokens"],
+                                              data_sharding)}
             rng = jax.random.fold_in(jax.random.PRNGKey(args.seed + 1), step)
             t0 = time.perf_counter()
             params, opt_state, metrics = step_fn(params, opt_state, batch, rng)

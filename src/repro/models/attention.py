@@ -236,7 +236,8 @@ def paged_attn_apply(
     from repro.kernels import ops
     out = ops.paged_attention_forward(
         q[:, 0], k_pool, v_pool, block_tables, positions + 1,
-        softcap=cfg.attn_logit_softcap, window=window, use_pallas=use_pallas)
+        softcap=cfg.attn_logit_softcap, window=window,
+        use_pallas=use_pallas if window is None else False)
     out = out.reshape(bsz, 1, cfg.num_heads * hd)
     y = linear(p["o"], out, rank=r.get("o"), tap="o")
     return y, k_pool, v_pool
@@ -287,7 +288,8 @@ def paged_prefill_attn_apply(
     from repro.kernels import ops
     out = ops.paged_prefill_attention_forward(
         q[0], k_pool, v_pool, block_tables, slot_ids, positions + 1,
-        softcap=cfg.attn_logit_softcap, window=window, use_pallas=use_pallas)
+        softcap=cfg.attn_logit_softcap, window=window,
+        use_pallas=use_pallas if window is None else False)
     out = out.reshape(1, t, cfg.num_heads * hd)
     y = linear(p["o"], out, rank=r.get("o"), tap="o")
     return y, k_pool, v_pool

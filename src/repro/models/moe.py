@@ -14,6 +14,7 @@ by the same nested rank machinery as dense layers.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -231,14 +232,7 @@ def moe_apply_ep(p: Dict, x: Array, cfg: ModelConfig, *,
                  ranks: Optional[Dict[str, Array]] = None) -> Tuple[Array, Array]:
     """shard_map EP MoE (train/prefill path on a mesh). Falls back to
     moe_apply when no mesh is active or token counts don't divide."""
-    try:
-        from jax import shard_map as _sm
-        import functools
-        shard_map = functools.partial(_sm, check_vma=False)
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sme
-        import functools
-        shard_map = functools.partial(_sme, check_rep=False)
+    shard_map = functools.partial(jax.shard_map, check_vma=False)
     from jax.sharding import PartitionSpec as P
     from repro.distributed.meshctx import get_current_mesh, data_axes
 

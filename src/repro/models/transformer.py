@@ -666,8 +666,9 @@ def _run_paged_segments(params, cfg, x, caches, ranks, attn_fn):
 
     ``attn_fn(p_attn, h, window, k_pool, v_pool, ranks)`` -> (y, k_pool,
     v_pool); ``window`` is the per-layer traced window, or None for
-    all-global configs (those hit the Pallas kernel; local-window layers
-    route to the oracle path inside ops.py). Returns (x, new segment pools).
+    all-global configs (those hit the Pallas kernel; the attention layer
+    serves windowed stacks on the oracle path, since the kernels have no
+    window). Returns (x, new segment pools).
     """
     windowed = bool(cfg.local_window and cfg.global_every)
     new_segments = []

@@ -114,7 +114,7 @@ def _warp_rows(rows: jax.Array, temperature: jax.Array,
         z = (rows.astype(jnp.float32)
              / jnp.maximum(jnp.asarray(temperature, jnp.float32),
                            1e-30)[:, None])
-        thr = ref.topk_threshold_ref(z, jnp.asarray(top_k, jnp.int32))
+        thr = ops.topk_threshold(z, top_k)
     return ref.warp_probs_ref(rows, jnp.asarray(temperature, jnp.float32),
                               thr)
 

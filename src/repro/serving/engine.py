@@ -207,7 +207,7 @@ class ElasticEngine:
                  lookahead: Optional[bool] = None,
                  tracer=None, registry=None,
                  watchdog=None, costaudit=None,
-                 use_pallas=False):
+                 use_pallas=None):
         self.cfg = cfg
         self.params_fact = params_fact
         self.table = table
@@ -216,6 +216,12 @@ class ElasticEngine:
         self.max_len = max_len
         self.block_size = block_size
         self.num_blocks = num_blocks
+        # Pallas kernels for paged attention and sampling: on by default
+        # exactly when the backend is a TPU (resolved once, here); False is
+        # the jnp oracle, and "interpret" runs the kernels through the
+        # Pallas interpreter (tests on the CPU)
+        if use_pallas is None:
+            use_pallas = jax.default_backend() == "tpu"
         self.use_pallas = use_pallas
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError(f"prefill_chunk must be >= 1, got {prefill_chunk}")
@@ -1376,7 +1382,7 @@ class ElasticEngine:
         one ``(sampler, purpose, position)`` per live row, aligned with
         ``sample_ids``. Greedy rows carry temperature 0 (in-jit argmax);
         ``top_k`` collapses to None when no row truncates so the common
-        case never pays the threshold sort (a distinct jit trace)."""
+        case never pays the top-k threshold (a distinct jit trace)."""
         temp = np.zeros(width, np.float32)
         topk = np.zeros(width, np.int32)
         seed = np.zeros(width, np.int32)

@@ -59,6 +59,24 @@ def test_datasvd_full_rank_exact(rng):
     assert np.abs(w - np.asarray(f.reconstruct())).max() < 1e-3
 
 
+def test_datasvd_host_matches_jnp(rng):
+    """numpy operands run the same factorization on the host (how
+    ``decompose`` calls it) and agree with the jnp path."""
+    w = rng.standard_normal((12, 10)).astype(np.float32)
+    x = _correlated_acts(rng, 10, 256)
+    moment = x.T @ x
+    dev = datasvd_factors(jnp.asarray(w), jnp.asarray(moment), 256.0)
+    host = datasvd_factors(w, moment, 256.0)
+    assert isinstance(host.u, np.ndarray) and isinstance(host.v, np.ndarray)
+    scale = np.abs(w).max()
+    np.testing.assert_allclose(host.reconstruct(4), np.asarray(
+        dev.reconstruct(4)), atol=1e-4 * scale)
+    plain_dev, plain_host = plain_svd_factors(jnp.asarray(w)), \
+        plain_svd_factors(w)
+    np.testing.assert_allclose(plain_host.reconstruct(4), np.asarray(
+        plain_dev.reconstruct(4)), atol=1e-4 * scale)
+
+
 def test_truncation_curve_monotone(rng):
     w = rng.standard_normal((16, 12)).astype(np.float32)
     x = _correlated_acts(rng, 12, 256)
@@ -143,6 +161,20 @@ def test_gar_handles_illconditioned_top_block(rng):
     g = gar_transform(jnp.asarray(u), jnp.asarray(v), 4)
     w_r = (u[:, :4] @ v[:, :4].T).astype(np.float32)
     np.testing.assert_allclose(np.asarray(reconstruction(g)), w_r, atol=1e-3)
+
+
+@pytest.mark.parametrize("m,r", [(16, 8), (40, 40), (64, 5)])
+def test_gar_pivots_are_partial_pivoting_rows(rng, m, r):
+    """The LU-based pivot search picks the rows of textbook Gaussian
+    elimination with partial pivoting, in order."""
+    from repro.core.gar import _pivot_rows
+    u = rng.standard_normal((m, r))
+    work, rows = u.copy(), np.arange(m)
+    for j in range(r):
+        p = j + int(np.argmax(np.abs(work[j:, j])))
+        work[[j, p]], rows[[j, p]] = work[[p, j]], rows[[p, j]]
+        work[j + 1:] -= np.outer(work[j + 1:, j] / work[j, j], work[j])
+    np.testing.assert_array_equal(_pivot_rows(u), rows)
 
 
 # ---------------------------------------------------------------- profiles

@@ -4,6 +4,7 @@ import os
 import subprocess
 import sys
 import tempfile
+import textwrap
 
 import numpy as np
 import jax
@@ -15,6 +16,8 @@ from repro.data import SyntheticTokens, MemmapTokens, make_source
 from repro.optim import adamw
 from repro.optim.compression import (PowerSGDConfig, compress_decompress, init
                                      as psgd_init)
+
+SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 # -------------------------------------------------------------------- data
@@ -220,6 +223,35 @@ def test_train_restart_resumes(tmp_path):
     # second invocation must resume from step 8 and do nothing more
     params, losses = train_main(args)
     assert losses == []
+
+
+def test_train_mesh_places_fresh_and_restored_state(tmp_path):
+    """``--mesh-shape 4,1`` on four virtual devices (a subprocess: the
+    suite keeps one): a fresh start and a restart from its checkpoint both
+    put the training state on all four devices, and the batch is split
+    across them."""
+    script = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import jax
+        from repro.launch.train import main
+        args = ["--arch", "gpt2-small", "--smoke", "--mode", "flexrank_kd",
+                "--seq-len", "16", "--batch", "4", "--ckpt-every", "2",
+                "--ckpt-dir", {str(tmp_path / "ck")!r}, "--mesh-shape", "4,1"]
+        fresh, _ = main(args + ["--steps", "2"])
+        restored, losses = main(args + ["--steps", "3"])
+        assert len(losses) == 1, losses
+        for params in (fresh, restored):
+            assert all(len(a.sharding.device_set) == 4
+                       for a in jax.tree.leaves(params))
+        print("PLACED")
+    """)
+    env = dict(os.environ, PYTHONPATH=SRC)
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-2000:]
+    assert "PLACED" in res.stdout
 
 
 # -------------------------------------------------------------------- muon

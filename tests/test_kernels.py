@@ -161,6 +161,25 @@ def test_paged_prefill_reduces_to_decode_and_respects_window():
         np.testing.assert_array_equal(np.asarray(y_dec), np.asarray(y_pre))
 
 
+@pytest.mark.parametrize("prefill", [False, True])
+@pytest.mark.parametrize("mode", [True, "interpret"])
+def test_paged_kernels_refuse_window(prefill, mode):
+    """A kernel asked for with a sliding window raises instead of quietly
+    running the oracle: windowed layers choose the oracle at the caller."""
+    b, hq, hkv, d, bs, mb = 2, 4, 2, 8, 4, 2
+    kp, vp, tables = _paged_pools(b, hkv, d, bs, mb, jnp.float32)
+    q = jnp.zeros((b, hq, d), jnp.float32)
+    lens = jnp.asarray(np.asarray([3, 5], np.int32))
+    with pytest.raises(ValueError, match="sliding window"):
+        if prefill:
+            ops.paged_prefill_attention_forward(
+                q, kp, vp, tables, jnp.arange(b, dtype=jnp.int32), lens,
+                window=3, use_pallas=mode)
+        else:
+            ops.paged_attention_forward(q, kp, vp, tables, lens, window=3,
+                                        use_pallas=mode)
+
+
 @pytest.mark.parametrize("hq,hkv,d,bs,mb", [(5, 5, 24, 3, 4),
                                             (12, 4, 40, 7, 3)])
 def test_paged_verify_runs_parity_nontile_shapes(hq, hkv, d, bs, mb):
@@ -239,6 +258,23 @@ def test_sampling_kernel_parity_sweep(s, v, bv):
     assert float(jnp.abs(p_ref - p_ker).max()) < 1e-5
     t_only = topk_mask_sample(logits, temps, thr, u, bv=bv, interpret=True)
     np.testing.assert_array_equal(np.asarray(t_ker), np.asarray(t_only))
+
+
+@pytest.mark.parametrize("v", [1, 7, 513])
+def test_topk_threshold_matches_sort(v):
+    """The bisection cutoff is bitwise the sort-based oracle's: ties at the
+    cutoff, negative and -inf entries, k of 0, 1, V and beyond V."""
+    rng = np.random.default_rng(v)
+    s = 12
+    z = np.round(rng.standard_normal((s, v)) * 4, 1).astype(np.float32)
+    z[0] = 0.0
+    z[1, : (v + 1) // 2] = -np.inf
+    z[2] = -np.abs(z[2])
+    topks = np.asarray([1, 1, 2, 0, v, v + 5] + list(
+        rng.integers(0, v + 1, s - 6)), np.int32)
+    got = ops.topk_threshold(jnp.asarray(z), jnp.asarray(topks))
+    want = ref.topk_threshold_ref(jnp.asarray(z), jnp.asarray(topks))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_sampling_dispatch_matches_host_oracle():
