@@ -37,6 +37,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
+from repro.obs import compiles                             # noqa: E402
 
 # max |kernel - oracle| logits over max |oracle logit|, both sides computed
 # with float32 matmuls. The two paths differ only in attention: float32
@@ -93,21 +94,6 @@ def print_device_line(info: dict) -> None:
           f"count={info['count']} jax={jax.__version__} "
           f"jaxlib={importlib.metadata.version('jaxlib')} libtpu={libtpu}",
           flush=True)
-
-
-class CompileClock:
-    """Sums JAX's backend-compile durations (persistent-cache loads
-    included) from the moment it is installed."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.count = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.count += 1
 
 
 # ------------------------------------------------------------------ build
@@ -308,10 +294,10 @@ def kernel_vs_oracle(cfg, params, tokens, caches, use_pallas):
 # ------------------------------------------------------------ the phases
 
 def run_serving(sc: SmokeConfig, *, on_chip: bool,
-                clock: CompileClock | None = None) -> dict:
+                clock: compiles.CompileRecord | None = None) -> dict:
     """Every one-chip phase in order; raises on the first failure. Returns
-    the numbers worth reporting. With a ``clock``, each phase line also
-    gives the compile seconds spent in it."""
+    the numbers worth reporting. With a ``clock`` (the program's compile
+    record), each phase line also gives the compile seconds spent in it."""
     report = {}
     mark = [clock.seconds if clock else 0.0]
 
@@ -433,7 +419,7 @@ def main(argv=None) -> int:
         return 1
     from repro.launch.compile_cache import enable_compile_cache
     print(f"# compile cache: {enable_compile_cache()}", flush=True)
-    clock = CompileClock()
+    clock = compiles.install()
     sc = SmokeConfig()
     if args.four_chips:
         run_four_chips(sc)

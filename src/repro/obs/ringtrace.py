@@ -95,6 +95,7 @@ class RingTracer(Tracer):
         events = self.chrome_events(last_s=last_s)
         return {"traceEvents": events,
                 "displayTimeUnit": "ms",
+                "otherData": {"t0_unix_ns": self.t0_unix_ns},
                 "ring": {"capacity": self.capacity,
                          "dropped": self._last_dump_dropped,
                          "events": len(events),

@@ -21,6 +21,9 @@ greppable). The taxonomy the serving stack emits:
   * ``sched`` — scheduler decisions **with reasons**: ``route``,
     ``admit``, ``preempt`` (victim + why), ``requeue``, ``adaptive_k``
     (grow/shrink/probe decisions).
+  * ``compile`` — one ``compile`` instant per XLA compile or
+    persistent-cache load in the process (``obs/compiles.py``), carrying
+    the jitted function's name, its seconds and the cache outcome.
 
 Overhead discipline: the disabled path must cost ~nothing in the engine
 hot loop. ``NULL_TRACER`` (a ``NullTracer``) is the shared disabled
@@ -34,7 +37,12 @@ Timestamps are ``time.perf_counter()`` seconds, rebased to the tracer's
 construction time and exported as integer microseconds (the Chrome
 format's unit). ``complete()`` accepts caller-measured ``(t0, t1)`` pairs
 so code that already times a phase (the metrics timing split) emits spans
-without a second clock read.
+without a second clock read. The Chrome export carries the wall-clock
+time of that zero, ``otherData.t0_unix_ns``: the JAX profiler stamps its
+events on the wall clock (an ``.xplane.pb`` relative to the
+``profile_start_time`` of its ``Task Environment`` plane), so an event's
+wall time, ``t0_unix_ns + ts * 1000``, lays the export over a profiler
+trace of the same process.
 """
 from __future__ import annotations
 
@@ -44,14 +52,15 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "make_tracer",
-           "validate_chrome_trace",
-           "CAT_REQUEST", "CAT_ITER", "CAT_SPEC", "CAT_ALLOC", "CAT_SCHED"]
+           "validate_chrome_trace", "CAT_REQUEST", "CAT_ITER", "CAT_SPEC",
+           "CAT_ALLOC", "CAT_SCHED", "CAT_COMPILE"]
 
 CAT_REQUEST = "request"
 CAT_ITER = "iteration"
 CAT_SPEC = "spec"
 CAT_ALLOC = "alloc"
 CAT_SCHED = "sched"
+CAT_COMPILE = "compile"
 
 # Chrome trace-event phases this tracer emits (the validator accepts
 # exactly these): X = complete span, B/E = begin/end span, i = instant,
@@ -77,6 +86,8 @@ class Tracer:
     def __init__(self, clock=time.perf_counter):
         self._clock = clock
         self._t0 = clock()
+        # the profiler's clock at ``_t0`` (read back to back with it)
+        self.t0_unix_ns = time.time_ns()
         self._lock = threading.Lock()
         # (ph, name, cat, ts_s, dur_s, tid, args) — dur_s only for X
         self._events: List[Tuple] = []
@@ -188,7 +199,8 @@ class Tracer:
 
     def to_chrome(self) -> dict:
         return {"traceEvents": self.chrome_events(),
-                "displayTimeUnit": "ms"}
+                "displayTimeUnit": "ms",
+                "otherData": {"t0_unix_ns": self.t0_unix_ns}}
 
     def export_chrome(self, path) -> None:
         with open(path, "w") as f:

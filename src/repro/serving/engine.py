@@ -74,11 +74,11 @@ from jax.experimental import io_callback
 from repro.configs.base import ModelConfig
 from repro.core import flexrank as FR
 from repro.models import transformer as tfm
-from repro.obs import CAT_ITER, CAT_SCHED, make_tracer, profiling
+from repro.obs import CAT_ITER, CAT_SCHED, compiles, make_tracer, profiling
 from repro.serving import device_sampling as dsamp
 from repro.serving.batcher import ContinuousBatcher
 from repro.serving.kv_cache import CacheOOM, PagedKVCache
-from repro.serving.metrics import ServingMetrics
+from repro.serving.metrics import Iteration, ServingMetrics
 from repro.serving.sampling import DRAW_TARGET, SamplerState
 from repro.serving.scheduler import (BudgetRouter, Request, Result, Scheduler,
                                      Sequence)
@@ -87,6 +87,14 @@ if TYPE_CHECKING:    # runtime import is lazy: repro.spec imports serving
     from repro.spec import SpecConfig    # submodules (cycle otherwise)
 
 __all__ = ["ElasticEngine", "Request", "Result", "CacheOOM"]
+
+
+def _named(name: str, fn):
+    """``fn`` under ``name``: the name JAX gives its compiled programs, as
+    the compile record (``obs/compiles.py``) and ``jax_log_compiles``
+    report them."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 class _ImmediateLog:
@@ -164,9 +172,7 @@ class _MixedPlan:
     __slots__ = ("plog", "empty", "decode_slots", "decode_seqs", "chunks",
                  "sample_ids", "metas", "finish_rows", "gen_patches",
                  "feed_rows", "registers", "admissions", "cancel_cursor",
-                 "total_chunk", "it0", "host_s", "commit_s", "sync_s",
-                 "overlap_s", "t_enqueue", "t_sync_end", "tokens_dev",
-                 "sampled")
+                 "total_chunk", "rec", "tokens_dev", "sampled")
 
     def __init__(self, plog):
         self.plog = plog
@@ -183,13 +189,7 @@ class _MixedPlan:
         self.admissions: list = []      # (seq, prefix-hit tokens at plan)
         self.cancel_cursor = 0
         self.total_chunk = 0
-        self.it0 = 0.0
-        self.host_s = 0.0
-        self.commit_s = 0.0
-        self.sync_s = 0.0
-        self.overlap_s = 0.0
-        self.t_enqueue = 0.0
-        self.t_sync_end = 0.0
+        self.rec: Optional[Iteration] = None   # the iteration's record
         self.tokens_dev = None
         self.sampled = None
 
@@ -340,15 +340,24 @@ class ElasticEngine:
         self._live: Dict[str, object] = {}
         self._iterations = 0
         self.last_metrics: Optional[ServingMetrics] = None
-        self._decode_jit = jax.jit(
-            lambda p, st, tok: tfm.decode_step(p, self.cfg, st, tok))
-        self._prefill_jit = jax.jit(
-            lambda p, st, tok: tfm.prefill(p, self.cfg, st, tok))
+        # the process's compile and GC record (installed once per process):
+        # iterations are charged what ended inside their phases, and a
+        # live tracer gets a ``compile`` instant per program
+        self._compiles = compiles.install()
+        self._compiles.engine_t = time.perf_counter()
+        if self.tracer.enabled:
+            self._compiles.subscribe(self.tracer)
+        self._decode_jit = jax.jit(_named(
+            "decode_step",
+            lambda p, st, tok: tfm.decode_step(p, self.cfg, st, tok)))
+        self._prefill_jit = jax.jit(_named(
+            "prefill", lambda p, st, tok: tfm.prefill(p, self.cfg, st, tok)))
         # caches donated: K/V pools update in place instead of copying the
         # whole pool every step
-        self._mixed_jit = jax.jit(
+        self._mixed_jit = jax.jit(_named(
+            "paged_mixed_step",
             lambda p, caches, tok: tfm.paged_mixed_step(
-                p, self.cfg, caches, tok, use_pallas=self.use_pallas),
+                p, self.cfg, caches, tok, use_pallas=self.use_pallas)),
             donate_argnums=(1,))
         # verify forward for speculative rounds: ``tfm.paged_verify_step``
         # (k+1 scored positions per sequence) IS the mixed-step computation,
@@ -359,40 +368,46 @@ class ElasticEngine:
         # returns int32 token ids only (probs variant feeds the speculative
         # draft phase, which keeps the warped q rows on device for the
         # accept test); the verify variant fuses Leviathan acceptance
-        self._sample_jit = jax.jit(
+        self._sample_jit = jax.jit(_named(
+            "paged_sample_step",
             lambda p, caches, tok, sampling: dsamp.paged_sample_step(
                 p, self.cfg, caches, tok, sampling,
-                use_pallas=self.use_pallas),
+                use_pallas=self.use_pallas)),
             donate_argnums=(1,))
-        self._sample_probs_jit = jax.jit(
+        self._sample_probs_jit = jax.jit(_named(
+            "paged_sample_probs_step",
             lambda p, caches, tok, sampling: dsamp.paged_sample_step(
                 p, self.cfg, caches, tok, sampling,
-                use_pallas=self.use_pallas, return_probs=True),
+                use_pallas=self.use_pallas, return_probs=True)),
             donate_argnums=(1,))
-        self._verify_accept_jit = jax.jit(
+        self._verify_accept_jit = jax.jit(_named(
+            "paged_verify_accept_step",
             lambda p, caches, tok, accept, chunk_sampling:
             dsamp.paged_verify_accept_step(
                 p, self.cfg, caches, tok, accept, chunk_sampling,
-                use_pallas=self.use_pallas),
+                use_pallas=self.use_pallas)),
             donate_argnums=(1,))
-        self._drain_sample_jit = jax.jit(
+        self._drain_sample_jit = jax.jit(_named(
+            "sample_rows",
             lambda rows, sampling: dsamp.sample_rows(
-                rows, sampling, use_pallas=self.use_pallas))
+                rows, sampling, use_pallas=self.use_pallas)))
         # identity on the sampled tokens, routed through a host callback
         # that sleeps ``_dispatch_delay`` seconds on the runtime thread
         # (GIL released) before the token future resolves — emulated
         # accelerator latency for the saturation benchmark. The callback is
         # a stable bound method so the jit cache holds one trace per shape.
-        self._delay_jit = jax.jit(
+        self._delay_jit = jax.jit(_named(
+            "dispatch_delay",
             lambda t: io_callback(self._sleep_cb,
-                                  jax.ShapeDtypeStruct(t.shape, t.dtype), t))
+                                  jax.ShapeDtypeStruct(t.shape, t.dtype), t)))
         # pipelined feed fixup: patch a dispatch's token batch from the
         # previous iteration's unsynced device token vector in ONE jitted
         # call — eager scatter/gather dispatch here costs ~2ms/iteration
         # on CPU, more than the dispatch gap the pipeline hides (one trace
         # per fixup count, bounded by max_batch)
-        self._fixup_jit = jax.jit(
-            lambda tok, pos, prev, rows: tok.at[0, pos].set(prev[rows]))
+        self._fixup_jit = jax.jit(_named(
+            "feed_fixup",
+            lambda tok, pos, prev, rows: tok.at[0, pos].set(prev[rows])))
 
     # ------------------------------------------------------------ routing
 
@@ -561,13 +576,14 @@ class ElasticEngine:
         speculative plan, so rollback snapshots never race an arrival."""
         if self._session is None:
             return
-        for request, handle in self._session.drain_new():
-            if len(request.prompt) == 0:
-                raise ValueError("empty prompt")
-            seq = sched.submit(request)
-            metrics.on_submit(seq.req_id)
-            self._seq_index[seq.req_id] = seq
-            self._session.register(handle, seq.req_id)
+        with profiling.annotate("engine.intake"):
+            for request, handle in self._session.drain_new():
+                if len(request.prompt) == 0:
+                    raise ValueError("empty prompt")
+                seq = sched.submit(request)
+                metrics.on_submit(seq.req_id)
+                self._seq_index[seq.req_id] = seq
+                self._session.register(handle, seq.req_id)
 
     def _finish(self, seq: Sequence, metrics, results) -> None:
         metrics.on_finish(seq.req_id)
@@ -738,6 +754,7 @@ class ElasticEngine:
             out["watchdog"] = self.watchdog.statusz()
         if self.costaudit is not None:
             out["costaudit"] = self.costaudit.statusz()
+        out["compiles"] = self._compiles.statusz()
         return out
 
     # ------------------------------ chunked prefill / mixed iterations
@@ -774,13 +791,15 @@ class ElasticEngine:
         with ``lookahead`` set and device sampling on — the one-iteration
         pipeline that dispatches iteration ``i+1`` from speculatively
         advanced host state before syncing and committing ``i``."""
-        params = self._realize(row)
-        cache = PagedKVCache(self.cfg, max_batch=self.max_batch,
-                             max_len=self.max_len, block_size=self.block_size,
-                             num_blocks=self.num_blocks,
-                             prefix_cache=self.prefix_cache)
-        cache.tracer = self.tracer
-        batcher = ContinuousBatcher(self.max_batch)
+        with profiling.annotate("engine.row_setup"):
+            params = self._realize(row)
+            cache = PagedKVCache(self.cfg, max_batch=self.max_batch,
+                                 max_len=self.max_len,
+                                 block_size=self.block_size,
+                                 num_blocks=self.num_blocks,
+                                 prefix_cache=self.prefix_cache)
+            cache.tracer = self.tracer
+            batcher = ContinuousBatcher(self.max_batch)
         self._live.update(row=row, cache=cache, batcher=batcher, spec=False)
         if self.lookahead and self.device_sampling:
             self._serve_row_pipelined(row, params, sched, cache, batcher,
@@ -923,106 +942,153 @@ class ElasticEngine:
                         results: Dict[int, Result]) -> None:
         """The serial driver: plan, dispatch, sync, commit — byte-identical
         event order and token streams to the pre-pipeline loop."""
-        tr = self.tracer
         plog = _ImmediateLog(self, metrics, results)
         while True:
-            it0 = metrics.now()
-            self._drain_intake(sched, metrics)
+            with profiling.step("engine.iteration", self._iterations):
+                if not self._serial_iteration(row, params, sched, cache,
+                                              batcher, metrics, results,
+                                              plog):
+                    return
+
+    def _serial_iteration(self, row: int, params, sched, cache, batcher,
+                          metrics: ServingMetrics, results: Dict[int, Result],
+                          plog: _ImmediateLog) -> bool:
+        """One iteration of the serial loop, recorded in an ``Iteration``;
+        False when the row drained."""
+        tr = self.tracer
+        rec = Iteration(t_plan=metrics.now())
+        mark = self._compiles.mark()
+        self._drain_intake(sched, metrics)
+        with profiling.annotate("engine.plan"):
             plan = self._plan_iteration(row, sched, cache, batcher,
                                         metrics, plog)
-            if plan.empty:
-                break
-            decode_slots, chunks = plan.decode_slots, plan.chunks
-            disp0 = metrics.now()
-            if tr.enabled:
-                tr.complete("plan", CAT_ITER, it0, disp0,
-                            args={"decode": len(decode_slots),
-                                  "chunks": len(chunks)})
+        if plan.empty:
+            return False
+        decode_slots, chunks = plan.decode_slots, plan.chunks
+        self._size_record(rec, plan)
+        disp0 = metrics.now()
+        if tr.enabled:
+            tr.complete("plan", CAT_ITER, rec.t_plan, disp0,
+                        args={"decode": len(decode_slots),
+                              "chunks": len(chunks)})
+        with profiling.annotate("engine.enqueue"):
+            out = self._dispatch_mixed(
+                params, cache, batcher, decode_slots, chunks,
+                plan.sample_ids,
+                plan.metas if self.device_sampling else None)
+        rec.t_enqueue = rec.t_planned = rec.t_sync0 = metrics.now()
+        with profiling.annotate("engine.sync"):
             if self.device_sampling:
-                logits = None
-                sampled = self._dispatch_mixed(params, cache, batcher,
-                                               decode_slots, chunks,
-                                               plan.sample_ids, plan.metas)
+                logits, sampled = None, np.asarray(out)
             else:
-                logits = self._dispatch_mixed(params, cache, batcher,
-                                              decode_slots, chunks,
-                                              plan.sample_ids)
                 # greedy fast path: argmax only the gathered sample rows,
                 # never the full flat-token batch
+                logits = out
                 sampled = np.array(jnp.argmax(logits[0], axis=-1), np.int32)
-            disp_s = metrics.now() - disp0
+        rec.t_sync1 = metrics.now()
+        disp_s = rec.t_sync1 - disp0
+        emit0 = self._emit_s()
+        metrics.open_iteration(rec)
+        with profiling.annotate("engine.commit"):
+            total_chunk = self._commit_serial(plan, sampled, logits, cache,
+                                              batcher, metrics, results)
+        rec.t_commit = it1 = metrics.now()
+        rec.emit_s = self._emit_s() - emit0
+        self._charge(rec, mark)
+        metrics.on_iteration_timing(disp_s, it1 - rec.t_plan - disp_s)
+        if tr.enabled:
+            tr.complete("dispatch", CAT_ITER, disp0, disp0 + disp_s,
+                        args={"sample_rows": len(plan.sample_ids)})
+            tr.complete("commit", CAT_ITER, disp0 + disp_s, it1,
+                        args={"decode": len(decode_slots),
+                              "prefill": total_chunk})
+        if self.registry is not None:
+            metrics.on_cache_stats(cache.allocator.free_count,
+                                   cache.allocator.fragmentation(),
+                                   prefix=cache.stats)
+            metrics.on_queue_depths(
+                {r: len(q) for r, q in sched.queues.items()})
+        self._iterations += 1
+        if self.costaudit is not None:
+            self.costaudit.observe(row, rec.width, disp_s)
+        if self.watchdog is not None:
+            self._watchdog_tick(metrics, cache, decoding=bool(decode_slots))
+        return True
 
-            # commit decodes first: `advance` must only see sequences that
-            # actually decoded this iteration, not freshly flipped ones
-            sampled_b = np.zeros(self.max_batch, np.int32)
-            for i, slot in enumerate(decode_slots):
-                seq = batcher.slots[slot]
+    def _commit_serial(self, plan: _MixedPlan, sampled, logits, cache,
+                       batcher, metrics: ServingMetrics,
+                       results: Dict[int, Result]) -> int:
+        """The serial loop's commit: decode tokens, then prefill chunks
+        (a finishing chunk's first generated token sits at its reserved
+        sample row), each token streamed as it lands. Returns the prompt
+        tokens committed."""
+        decode_slots = plan.decode_slots
+        # commit decodes first: `advance` must only see sequences that
+        # actually decoded this iteration, not freshly flipped ones
+        sampled_b = np.zeros(self.max_batch, np.int32)
+        for i, slot in enumerate(decode_slots):
+            seq = batcher.slots[slot]
+            if logits is not None and not seq.sampler.greedy:
+                sampled[i] = seq.sampler.sample(np.asarray(logits[0, i]))
+            sampled_b[slot] = sampled[i]
+            metrics.on_token(seq.req_id)
+            if self._session is not None:
+                self._session.emit(seq.req_id, len(seq.generated),
+                                   int(sampled[i]))
+        for slot in batcher.advance(sampled_b):
+            seq = batcher.leave(slot)
+            cache.free_slot(slot)
+            self._finish(seq, metrics, results)
+
+        total_chunk = 0
+        for slot, seq, start, n in plan.chunks:
+            seq.prefill_pos = start + n
+            total_chunk += n
+            metrics.on_prefill_chunk(n)
+            # the chunk's K/V is on device now — index every prompt
+            # block it completed so later admissions can share it
+            cache.register_prefix(slot, seq.request.prompt,
+                                  seq.prefill_pos)
+            if seq.prefill_pos == seq.prompt_len:
+                metrics.on_prefill_end(seq.req_id)
+                ri = plan.finish_rows[slot]
+                first = int(sampled[ri])
                 if logits is not None and not seq.sampler.greedy:
-                    sampled[i] = seq.sampler.sample(np.asarray(logits[0, i]))
-                sampled_b[slot] = sampled[i]
-                metrics.on_token(seq.req_id)
+                    first = seq.sampler.sample(np.asarray(logits[0, ri]))
                 if self._session is not None:
                     self._session.emit(seq.req_id, len(seq.generated),
-                                       int(sampled[i]))
-            for slot in batcher.advance(sampled_b):
-                seq = batcher.leave(slot)
-                cache.free_slot(slot)
-                self._finish(seq, metrics, results)
+                                       first)
+                seq.generated.append(first)
+                metrics.on_first_token(seq.req_id)
+                if seq.done:             # max_new_tokens == 1
+                    batcher.leave(slot)
+                    cache.free_slot(slot)
+                    self._finish(seq, metrics, results)
+                else:
+                    batcher.to_decoding(slot, first)
+        metrics.on_mixed_step(len(decode_slots), total_chunk,
+                              cache.occupancy())
+        return total_chunk
 
-            # commit prefill chunks; a finishing chunk's first generated
-            # token sits at its reserved sample row
-            total_chunk = 0
-            for slot, seq, start, n in chunks:
-                seq.prefill_pos = start + n
-                total_chunk += n
-                metrics.on_prefill_chunk(n)
-                # the chunk's K/V is on device now — index every prompt
-                # block it completed so later admissions can share it
-                cache.register_prefix(slot, seq.request.prompt,
-                                      seq.prefill_pos)
-                if seq.prefill_pos == seq.prompt_len:
-                    metrics.on_prefill_end(seq.req_id)
-                    ri = plan.finish_rows[slot]
-                    first = int(sampled[ri])
-                    if logits is not None and not seq.sampler.greedy:
-                        first = seq.sampler.sample(
-                            np.asarray(logits[0, ri]))
-                    if self._session is not None:
-                        self._session.emit(seq.req_id, len(seq.generated),
-                                           first)
-                    seq.generated.append(first)
-                    metrics.on_first_token(seq.req_id)
-                    if seq.done:             # max_new_tokens == 1
-                        batcher.leave(slot)
-                        cache.free_slot(slot)
-                        self._finish(seq, metrics, results)
-                    else:
-                        batcher.to_decoding(slot, first)
-            metrics.on_mixed_step(len(decode_slots), total_chunk,
-                                  cache.occupancy())
-            it1 = metrics.now()
-            metrics.on_iteration_timing(disp_s, it1 - it0 - disp_s)
-            if tr.enabled:
-                tr.complete("dispatch", CAT_ITER, disp0, disp0 + disp_s,
-                            args={"sample_rows": len(plan.sample_ids)})
-                tr.complete("commit", CAT_ITER, disp0 + disp_s, it1,
-                            args={"decode": len(decode_slots),
-                                  "prefill": total_chunk})
-            if self.registry is not None:
-                metrics.on_cache_stats(cache.allocator.free_count,
-                                       cache.allocator.fragmentation(),
-                                       prefix=cache.stats)
-                metrics.on_queue_depths(
-                    {r: len(q) for r, q in sched.queues.items()})
-            self._iterations += 1
-            if self.costaudit is not None:
-                self.costaudit.observe(
-                    row,
-                    self._bucket_tokens(len(decode_slots) + total_chunk),
-                    disp_s)
-            if self.watchdog is not None:
-                self._watchdog_tick(metrics, cache,
-                                    decoding=bool(decode_slots))
+    # --------------------------------------------- the iteration record
+
+    def _size_record(self, rec: Iteration, plan: _MixedPlan) -> None:
+        rec.sampled_rows = len(plan.sample_ids)
+        rec.width = self._bucket_tokens(len(plan.decode_slots)
+                                        + plan.total_chunk)
+
+    def _emit_s(self) -> float:
+        return self._session.emit_s if self._session is not None else 0.0
+
+    def _charge(self, rec: Iteration, mark) -> None:
+        """Charge ``rec`` with the compiles and GC seconds the process
+        recorded since ``mark``."""
+        events, gc_s = self._compiles.since(mark)
+        rec.gc_s += gc_s
+        if events:
+            rec.compiles += len(events)
+            rec.compile_s += sum(c.seconds for c in events)
+            rec.compile_funs += tuple(c.fun_name for c in events)
 
     # ------------------------------------- one-iteration-lookahead pipeline
 
@@ -1143,16 +1209,22 @@ class ElasticEngine:
         """Sync the pending iteration's device tokens (the pipeline's ONLY
         host<->device sync) and commit it: patch real values in, advance
         the committed cancellation cursor, flush the deferred emissions."""
-        t_sync0 = metrics.now()
-        pending.sampled = np.asarray(pending.tokens_dev)
-        pending.t_sync_end = metrics.now()
-        pending.sync_s = pending.t_sync_end - t_sync0
-        pending.overlap_s = max(0.0, t_sync0 - pending.t_enqueue)
-        c0 = metrics.now()
-        self._commit_apply(pending, batcher)
-        self._cancel_cursor = max(self._cancel_cursor, pending.cancel_cursor)
-        pending.plog.flush()
-        pending.commit_s = metrics.now() - c0
+        rec = pending.rec
+        mark = self._compiles.mark()
+        rec.t_sync0 = metrics.now()
+        with profiling.annotate("engine.sync"):
+            pending.sampled = np.asarray(pending.tokens_dev)
+        rec.t_sync1 = metrics.now()
+        emit0 = self._emit_s()
+        metrics.open_iteration(rec)
+        with profiling.annotate("engine.commit"):
+            self._commit_apply(pending, batcher)
+            self._cancel_cursor = max(self._cancel_cursor,
+                                      pending.cancel_cursor)
+            pending.plog.flush()
+        rec.t_commit = metrics.now()
+        rec.emit_s = self._emit_s() - emit0
+        self._charge(rec, mark)
 
     def _validate_speculation(self, plan: _MixedPlan,
                               cache) -> Optional[str]:
@@ -1188,6 +1260,8 @@ class ElasticEngine:
         recompute replays the same tokens). Finally replay the committed
         iteration's value patches, which the restore undid (its emissions
         already flushed and stay flushed)."""
+        if pending is not None:
+            pending.rec.rollback = reason
         self._restore_row(snap, sched, cache, batcher)
         for b in touched:
             cache._unregister_block(b)
@@ -1216,16 +1290,16 @@ class ElasticEngine:
         ``overlap_s``), trace spans anchored at the real enqueue/sync
         times, registry stats, cost-model audit, watchdog heartbeat."""
         tr = self.tracer
-        metrics.on_iteration_timing(pending.sync_s,
-                                    pending.host_s + pending.commit_s,
-                                    overlap_s=pending.overlap_s)
+        rec = pending.rec
+        sync_s = rec.t_sync1 - rec.t_sync0
+        overlap_s = max(0.0, rec.t_sync0 - rec.t_enqueue)
+        host_s = (rec.t_planned - rec.t_plan) + (rec.t_commit - rec.t_sync1)
+        metrics.on_iteration_timing(sync_s, host_s, overlap_s=overlap_s)
         if tr.enabled:
-            tr.complete("dispatch", CAT_ITER, pending.t_enqueue,
-                        pending.t_sync_end,
+            tr.complete("dispatch", CAT_ITER, rec.t_enqueue, rec.t_sync1,
                         args={"sample_rows": len(pending.sample_ids),
-                              "overlap_s": round(pending.overlap_s, 6)})
-            tr.complete("commit", CAT_ITER, pending.t_sync_end,
-                        pending.t_sync_end + pending.commit_s,
+                              "overlap_s": round(overlap_s, 6)})
+            tr.complete("commit", CAT_ITER, rec.t_sync1, rec.t_commit,
                         args={"decode": len(pending.decode_slots),
                               "prefill": pending.total_chunk})
         if self.registry is not None:
@@ -1238,11 +1312,7 @@ class ElasticEngine:
         if self.costaudit is not None:
             # estimated device time: visible sync wait plus the host work
             # the dispatch ran under
-            self.costaudit.observe(
-                row,
-                self._bucket_tokens(len(pending.decode_slots)
-                                    + pending.total_chunk),
-                pending.sync_s + pending.overlap_s)
+            self.costaudit.observe(row, rec.width, sync_s + overlap_s)
         if self.watchdog is not None:
             self._watchdog_tick(metrics, cache,
                                 decoding=bool(pending.decode_slots))
@@ -1273,57 +1343,72 @@ class ElasticEngine:
         pending: Optional[_MixedPlan] = None
         snap = None
         while True:
-            speculating = pending is not None
-            if speculating:
-                snap = self._snapshot_row(sched, cache, batcher)
-                cache.allocator.begin_alloc_log()
-                metrics.on_lookahead()
-            plog = _DeferredLog(self, metrics, results)
-            t0 = metrics.now()
-            plan = self._plan_iteration(row, sched, cache, batcher,
-                                        metrics, plog)
-            plan.it0 = t0
-            if not plan.empty:
-                fixups = (self._feed_fixups(plan, pending)
-                          if speculating else [])
-                plan.tokens_dev = self._dispatch_mixed_async(
-                    params, cache, batcher, plan,
-                    pending.tokens_dev if speculating else None, fixups)
-                plan.t_enqueue = metrics.now()
-                self._advance_predicted(plan, cache, batcher, metrics)
-            plan.host_s = metrics.now() - t0
-            if tr.enabled:
-                # every "lookahead" span ends in exactly one
-                # "lookahead_commit" or "rollback" instant (CI invariant)
-                tr.complete("lookahead" if speculating else "plan",
-                            CAT_ITER, t0, t0 + plan.host_s,
-                            args={"decode": len(plan.decode_slots),
-                                  "chunks": len(plan.chunks),
-                                  "empty": plan.empty})
-            if speculating:
-                self._commit_iteration(pending, batcher, metrics)
-                reason = self._validate_speculation(plan, cache)
-                touched = cache.allocator.end_alloc_log()
-                if reason is None:
-                    if tr.enabled:
-                        tr.instant("lookahead_commit", CAT_ITER,
-                                   args={"iter": self._iterations})
+            with profiling.step("engine.iteration", self._iterations):
+                speculating = pending is not None
+                if speculating:
+                    snap = self._snapshot_row(sched, cache, batcher)
+                    cache.allocator.begin_alloc_log()
+                    metrics.on_lookahead()
+                plog = _DeferredLog(self, metrics, results)
+                rec = Iteration(t_plan=metrics.now())
+                mark = self._compiles.mark()
+                with profiling.annotate("engine.lookahead" if speculating
+                                        else "engine.plan"):
+                    plan = self._plan_iteration(row, sched, cache, batcher,
+                                                metrics, plog)
+                    plan.rec = rec
+                    if not plan.empty:
+                        self._size_record(rec, plan)
+                        fixups = (self._feed_fixups(plan, pending)
+                                  if speculating else [])
+                        with profiling.annotate("engine.enqueue"):
+                            plan.tokens_dev = self._dispatch_mixed_async(
+                                params, cache, batcher, plan,
+                                pending.tokens_dev if speculating else None,
+                                fixups)
+                        rec.t_enqueue = metrics.now()
+                        self._advance_predicted(plan, cache, batcher,
+                                                metrics)
+                rec.t_planned = metrics.now()
+                self._charge(rec, mark)
+                if tr.enabled:
+                    # every "lookahead" span ends in exactly one
+                    # "lookahead_commit" or "rollback" instant (CI invariant)
+                    tr.complete("lookahead" if speculating else "plan",
+                                CAT_ITER, rec.t_plan, rec.t_planned,
+                                args={"decode": len(plan.decode_slots),
+                                      "chunks": len(plan.chunks),
+                                      "empty": plan.empty})
+                if speculating:
+                    self._commit_iteration(pending, batcher, metrics)
+                    reason = self._validate_speculation(plan, cache)
+                    touched = cache.allocator.end_alloc_log()
+                    if reason is None:
+                        if tr.enabled:
+                            tr.instant("lookahead_commit", CAT_ITER,
+                                       args={"iter": self._iterations})
+                    else:
+                        # the thrown-away plan's record is dropped; what
+                        # stalled its planning turn held up this commit
+                        pr = pending.rec
+                        pr.compiles += rec.compiles
+                        pr.compile_s += rec.compile_s
+                        pr.compile_funs += rec.compile_funs
+                        pr.gc_s += rec.gc_s
+                        with profiling.annotate("engine.rollback"):
+                            self._rollback(snap, touched, pending, sched,
+                                           cache, batcher, metrics, reason)
                     self._finalize_iteration(row, pending, sched, cache,
                                              metrics)
                     pending = None
-                else:
-                    self._rollback(snap, touched, pending, sched, cache,
-                                   batcher, metrics, reason)
-                    self._finalize_iteration(row, pending, sched, cache,
-                                             metrics)
-                    pending = None
-                    self._drain_intake(sched, metrics)
-                    continue                 # replan from committed state
-            self._drain_intake(sched, metrics)
-            if plan.empty:
-                plan.plog.flush()            # cancel/zero-token finishes
-                break
-            pending = plan
+                    if reason is not None:
+                        self._drain_intake(sched, metrics)
+                        continue             # replan from committed state
+                self._drain_intake(sched, metrics)
+                if plan.empty:
+                    plan.plog.flush()        # cancel/zero-token finishes
+                    break
+                pending = plan
 
     @staticmethod
     def _pack_flat(entries, width: int, null_slot: int):
@@ -1431,13 +1516,13 @@ class ElasticEngine:
 
     def _dispatch_mixed(self, params, cache, batcher, decode_slots, chunks,
                         sample_ids, metas=None):
-        """Build the flat token batch and run one fused forward over it.
+        """Build the flat token batch and enqueue one fused forward over it.
 
         With ``metas`` (device-sampling path) the step samples in-jit and
-        returns the (S_pad,) int32 tokens as a host array — the whole
-        device->host traffic of the iteration. Without it, returns the
-        gathered (1, S_pad, V) logits rows for host-side sampling (the
-        oracle path)."""
+        returns the (S_pad,) int32 tokens, still on the device: syncing
+        them is the whole device->host traffic of the iteration. Without
+        it, returns the gathered (1, S_pad, V) logits rows for host-side
+        sampling (the oracle path)."""
         tok, caches, rows = self._build_mixed_operands(
             cache, batcher, decode_slots, chunks, sample_ids)
         if metas is not None:
@@ -1449,7 +1534,7 @@ class ElasticEngine:
             cache.update_pools(new_caches)
             if self._dispatch_delay > 0.0:
                 tokens = self._delay_jit(tokens)
-            return np.asarray(tokens)
+            return tokens
         with profiling.annotate("paged_mixed_step"):
             logits, new_caches = self._mixed_jit(params, caches,
                                                  jnp.asarray(tok[None]))

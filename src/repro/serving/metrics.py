@@ -1,9 +1,9 @@
 """Serving metrics: tokens/s, time-to-first-token (broken into queue /
-prefill / first-decode), KV-cache occupancy, per-iteration token-budget
-accounting for mixed prefill/decode iterations, a per-iteration
-dispatch/host wall-time split (the device-resident sampling pipeline's
-observable), and draft/verify acceptance accounting for speculative
-decoding rounds.
+prefill / first-decode), one record per engine iteration (``Iteration``:
+its phase timestamps, token-budget accounting, KV-cache occupancy, the
+dispatch/host wall-time split, and what stalled it — rollbacks, stream
+hand-offs, compiles, GC), and draft/verify acceptance accounting for
+speculative decoding rounds.
 
 Collected host-side by the engine loop (one sample per scheduler iteration)
 — cheap enough to stay on for production traffic.
@@ -44,6 +44,45 @@ def _pct(xs: List[float], q: float) -> float:
 
 def _mean(xs: List[float]) -> float:
     return sum(xs) / len(xs) if xs else 0.0
+
+
+@dataclasses.dataclass(slots=True)
+class Iteration:
+    """One engine iteration. Times are the metrics clock, stamped by the
+    engine where it already reads it (None where no engine stamped them:
+    the speculative rounds, callers of the callbacks alone).
+
+    Serial loop: ``t_plan`` (intake, then planning) -> ``t_enqueue``
+    (the step dispatched) = ``t_planned`` = ``t_sync0`` -> ``t_sync1``
+    (its tokens on the host) -> ``t_commit`` (committed and streamed).
+    Lookahead loop: the planning turn ``[t_plan, t_planned]`` (plan,
+    enqueue, predicted advance) runs while the previous iteration is on
+    the device; ``[t_sync0, t_commit]`` follows the next iteration's
+    planning turn. ``compiles``/``compile_s``/``compile_funs`` and
+    ``gc_s`` count what ended inside those phases; ``emit_s`` is the time
+    the engine thread blocked handing tokens to a ``StreamSession``
+    during the commit; ``rollback`` names why the speculative plan made
+    while this iteration was in flight was thrown away."""
+    t_plan: Optional[float] = None
+    t_enqueue: Optional[float] = None
+    t_planned: Optional[float] = None
+    t_sync0: Optional[float] = None
+    t_sync1: Optional[float] = None
+    t_commit: Optional[float] = None
+    decode_rows: int = 0
+    prefill_tokens: int = 0
+    sampled_rows: int = 0
+    width: int = 0                       # padded token width (bucket)
+    occupancy: Optional[float] = None
+    dispatch_s: float = 0.0
+    host_s: float = 0.0
+    overlap_s: float = 0.0
+    rollback: Optional[str] = None
+    emit_s: float = 0.0
+    compiles: int = 0
+    compile_s: float = 0.0
+    compile_funs: Tuple[str, ...] = ()
+    gc_s: float = 0.0
 
 
 @dataclasses.dataclass
@@ -178,26 +217,13 @@ class ServingMetrics:
         # rules key off this advancing (see obs/watchdog.py)
         self.generated_tokens = 0
         self.preemptions = 0
-        self.occupancy_samples: List[float] = []
-        # one (decode_tokens, prefill_tokens) pair per mixed iteration —
-        # the token-budget audit trail for the chunked-prefill engine
-        self.iteration_log: List[Tuple[int, int]] = []
+        # one record per iteration, in commit order; ``on_iteration_timing``
+        # appends the open one (``open_iteration``, or a fresh record)
+        self.iterations: List[Iteration] = []
+        self._open: Optional[Iteration] = None
         # one (draft_tokens, verify_tokens, accepted_tokens, drafting_seqs)
         # tuple per speculative round — the draft/verify audit trail
         self.spec_round_log: List[Tuple[int, int, int, int]] = []
-        # one (dispatch_s, host_s, overlap_s) triple per iteration.
-        # dispatch_s: the VISIBLE wait on the device — time the host spent
-        # blocked syncing the iteration's outputs; host_s: everything else
-        # the iteration spent on the host (planning, commits, python
-        # sampling on the host-oracle path); overlap_s: device time hidden
-        # under host work by lookahead pipelining (the window between
-        # enqueueing the dispatch and starting the sync, during which the
-        # device ran while the host planned the next iteration). Serial
-        # engines report overlap_s = 0 and dispatch_s = full device time.
-        # The attribution invariant either way: wall-clock ~ sum(dispatch)
-        # + sum(host) — overlapped device time is never double-counted
-        # (pinned by the scripted-clock test in tests/test_metrics.py).
-        self.timing_log: List[Tuple[float, float, float]] = []
         # pipelined-engine counters: speculatively planned iterations,
         # rollbacks (plan invalidated by the prior commit) by reason, and
         # client cancellations
@@ -215,6 +241,31 @@ class ServingMetrics:
 
     def now(self) -> float:
         return self._clock()
+
+    # ------------------------------------------------- iteration record
+
+    def open_iteration(self, rec: Iteration) -> None:
+        """Make ``rec`` the record the next ``on_mixed_step`` and
+        ``on_iteration_timing`` fill; the latter closes it."""
+        self._open = rec
+
+    def _current(self) -> Iteration:
+        if self._open is None:
+            self._open = Iteration()
+        return self._open
+
+    @property
+    def iteration_log(self) -> List[Tuple[int, int]]:
+        """(decode_tokens, prefill_tokens) per iteration — the
+        token-budget audit trail for the chunked-prefill engine."""
+        return [(r.decode_rows, r.prefill_tokens) for r in self.iterations]
+
+    @property
+    def timing_log(self) -> List[Tuple[float, float, float]]:
+        """(dispatch_s, host_s, overlap_s) per iteration; see
+        ``on_iteration_timing``."""
+        return [(r.dispatch_s, r.host_s, r.overlap_s)
+                for r in self.iterations]
 
     def on_submit(self, req_id: int) -> None:
         t = self.now()
@@ -280,20 +331,16 @@ class ServingMetrics:
             if prefill_tokens:
                 self._m_prefill.inc(prefill_tokens)
 
-    def on_decode_step(self, new_tokens: int, occupancy: float) -> None:
-        self.decode_steps += 1
-        self.occupancy_samples.append(occupancy)
-        if self.registry is not None:
-            self._m_occ.set(occupancy)
-
     def on_mixed_step(self, decode_tokens: int, prefill_tokens: int,
                       occupancy: float) -> None:
         """One mixed prefill/decode iteration: ``decode_tokens`` sequences
         advanced a token and ``prefill_tokens`` prompt tokens rode along."""
-        self.iteration_log.append((decode_tokens, prefill_tokens))
+        rec = self._current()
+        rec.decode_rows = decode_tokens
+        rec.prefill_tokens = prefill_tokens
+        rec.occupancy = occupancy
         if decode_tokens:
             self.decode_steps += 1
-        self.occupancy_samples.append(occupancy)
         if self.tracer.enabled:
             self.tracer.counter("kv_occupancy", occupancy)
         if self.registry is not None:
@@ -376,9 +423,15 @@ class ServingMetrics:
         ``overlap_s``: device time hidden under host work (0 for serial
         engines). ``dispatch_s + host_s`` always sums to the iteration's
         wall-clock share — overlapped time is attributed once, to the host
-        work that hid it, never double-counted."""
-        self.timing_log.append((dispatch_s, max(host_s, 0.0),
-                                max(overlap_s, 0.0)))
+        work that hid it, never double-counted (pinned by the
+        scripted-clock test in tests/test_metrics.py). Closes the
+        iteration's record."""
+        rec = self._current()
+        rec.dispatch_s = dispatch_s
+        rec.host_s = max(host_s, 0.0)
+        rec.overlap_s = max(overlap_s, 0.0)
+        self.iterations.append(rec)
+        self._open = None
         if self.registry is not None:
             self._m_disp.observe(dispatch_s)
             self._m_host.observe(max(host_s, 0.0))
@@ -495,7 +548,9 @@ class ServingMetrics:
         end = self._end if self._end is not None else self.now()
         start = self._start if self._start is not None else end
         wall = (end - start) or 1e-9
-        occ = self.occupancy_samples
+        occ = [r.occupancy for r in self.iterations
+               if r.occupancy is not None]
+        timing = self.timing_log
         return {
             "requests": len(self.traces),
             "generated_tokens": gen,
@@ -507,18 +562,18 @@ class ServingMetrics:
             "ttft_prefill_mean_s": _mean([p[1] for p in parts]),
             "ttft_first_decode_mean_s": _mean([p[2] for p in parts]),
             "decode_steps": self.decode_steps,
-            "mixed_iterations": len(self.iteration_log),
-            "dispatch_ms_mean": _mean([t[0] for t in self.timing_log]) * 1e3,
-            "host_ms_mean": _mean([t[1] for t in self.timing_log]) * 1e3,
-            "dispatch_s_total": sum(t[0] for t in self.timing_log),
-            "host_s_total": sum(t[1] for t in self.timing_log),
-            "overlap_ms_mean": _mean([t[2] for t in self.timing_log]) * 1e3,
-            "overlap_s_total": sum(t[2] for t in self.timing_log),
+            "mixed_iterations": len(self.iterations),
+            "dispatch_ms_mean": _mean([t[0] for t in timing]) * 1e3,
+            "host_ms_mean": _mean([t[1] for t in timing]) * 1e3,
+            "dispatch_s_total": sum(t[0] for t in timing),
+            "host_s_total": sum(t[1] for t in timing),
+            "overlap_ms_mean": _mean([t[2] for t in timing]) * 1e3,
+            "overlap_s_total": sum(t[2] for t in timing),
             # fraction of total device busy time hidden under host work:
             # overlap / (overlap + visible dispatch). 0 for serial engines.
             "overlap_fraction": (
-                sum(t[2] for t in self.timing_log)
-                / max(sum(t[0] + t[2] for t in self.timing_log), 1e-12)),
+                sum(t[2] for t in timing)
+                / max(sum(t[0] + t[2] for t in timing), 1e-12)),
             "lookahead_iterations": self.lookahead_iterations,
             "rollbacks": self.rollbacks,
             "cancellations": self.cancellations,

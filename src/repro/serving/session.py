@@ -32,7 +32,10 @@ from __future__ import annotations
 import asyncio
 import concurrent.futures
 import threading
+import time
 from typing import List, Optional, Tuple
+
+from repro.obs import profiling
 
 __all__ = ["StreamHandle", "StreamSession", "stream_request"]
 
@@ -111,6 +114,10 @@ class StreamSession:
         self._by_id: dict = {}
         self._work = threading.Event()
         self._done = threading.Event()
+        # seconds the engine thread has spent blocked handing tokens and
+        # results to clients (``emit``/``finish``); the engine charges the
+        # difference across a commit to its iteration record
+        self.emit_s = 0.0
 
     # ------------------------------------------------ client (loop) side
 
@@ -167,7 +174,8 @@ class StreamSession:
         self._done.set()
 
     def wait_for_work(self, timeout: float) -> None:
-        self._work.wait(timeout)
+        with profiling.annotate("engine.idle_wait"):
+            self._work.wait(timeout)
         self._work.clear()
 
     def drain_new(self) -> List[Tuple[object, StreamHandle]]:
@@ -212,10 +220,19 @@ class StreamSession:
 
     def _deliver(self, h: StreamHandle, item) -> None:
         """Blocking put from the engine thread into the handle's bounded
-        queue, polling the cancellation flag so an abandoned consumer never
-        wedges the engine."""
+        queue (an ``engine.emit`` profiler scope, timed into ``emit_s``)."""
         if self.loop is None:
             return
+        t0 = time.perf_counter()
+        try:
+            with profiling.annotate("engine.emit"):
+                self._put(h, item)
+        finally:
+            self.emit_s += time.perf_counter() - t0
+
+    def _put(self, h: StreamHandle, item) -> None:
+        """The cross-thread put, polling the cancellation flag so an
+        abandoned consumer never wedges the engine."""
         fut = asyncio.run_coroutine_threadsafe(h.queue.put(item), self.loop)
         while True:
             try:
