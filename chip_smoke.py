@@ -248,8 +248,8 @@ def mixed_operands(sc: SmokeConfig, cfg, seed: int = 1):
     keys = iter(jax.random.split(jax.random.PRNGKey(seed),
                                  2 * len(cfg.segments)))
     pools = [{kv: jax.random.normal(next(keys), (seg.count, nb, bs,
-                                                 cfg.num_kv_heads,
-                                                 cfg.resolved_head_dim))
+                                                 cfg.num_kv_heads
+                                                 * cfg.resolved_head_dim))
               for kv in ("k", "v")} for seg in cfg.segments]
     tokens = rng.integers(0, cfg.vocab_size, (1, width)).astype(np.int32)
     caches = {"slot_ids": jnp.asarray(slot_ids),

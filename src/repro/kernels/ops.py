@@ -97,7 +97,7 @@ def lowrank_forward(x: jax.Array, v: jax.Array, u: jax.Array,
 def paged_attention_forward(q, k_pool, v_pool, block_tables, context_lens, *,
                             softcap: float = 0.0, window=None,
                             use_pallas=False):
-    """Paged decode attention. q: (B, Hq, D); pools: (NB, BS, Hkv, D);
+    """Paged decode attention. q: (B, Hq, D); pools: (NB, BS, Hkv * D);
     block_tables: (B, MB); context_lens: (B,). Returns (B, Hq, D).
 
     ``window`` (sliding-window lookback) is only supported on the oracle
@@ -119,7 +119,7 @@ def paged_prefill_attention_forward(q, k_pool, v_pool, block_tables, slot_ids,
                                     context_lens, *, softcap: float = 0.0,
                                     window=None, use_pallas=False):
     """Chunked-prefill paged attention over a flat token batch (mixed
-    prefill/decode iterations). q: (T, Hq, D); pools: (NB, BS, Hkv, D);
+    prefill/decode iterations). q: (T, Hq, D); pools: (NB, BS, Hkv * D);
     block_tables: (B, MB); slot_ids/context_lens: (T,). Returns (T, Hq, D).
 
     ``window`` (sliding-window lookback) is only supported on the oracle
@@ -147,21 +147,33 @@ def topk_threshold(z, top_k):
     ``t`` with ``count(key >= t) >= k`` is found in 32 compare-and-count
     passes over the row, and is the k-th largest key itself.
 
-    z: (S, V) float32; top_k: (S,) int32, 0 = no truncation (-inf)."""
-    bits = jax.lax.bitcast_convert_type(z.astype(jnp.float32), jnp.uint32)
-    sign = jnp.uint32(0x80000000)
-    key = jnp.where(bits >= sign, ~bits, bits | sign)     # monotone in z
-    k = jnp.clip(jnp.asarray(top_k, jnp.int32), 1, z.shape[-1])
+    z: (S, V) float32; top_k: (S,) int32, 0 = no truncation (-inf). When
+    no row truncates, a run-time branch skips the passes: one program
+    serves batches with and without top-k rows."""
+    z = z.astype(jnp.float32)
+    top_k = jnp.asarray(top_k, jnp.int32)
 
-    def set_bit(i, t):
-        cand = t | (sign >> i.astype(jnp.uint32))
-        enough = jnp.sum(key >= cand[:, None], axis=-1) >= k
-        return jnp.where(enough, cand, t)
+    def bisect(z, top_k):
+        bits = jax.lax.bitcast_convert_type(z, jnp.uint32)
+        sign = jnp.uint32(0x80000000)
+        key = jnp.where(bits >= sign, ~bits, bits | sign)  # monotone in z
+        k = jnp.clip(top_k, 1, z.shape[-1])
 
-    t = jax.lax.fori_loop(0, 32, set_bit, jnp.zeros(z.shape[:1], jnp.uint32))
-    thr = jax.lax.bitcast_convert_type(jnp.where(t >= sign, t ^ sign, ~t),
-                                       jnp.float32)
-    return jnp.where(jnp.asarray(top_k) > 0, thr, -jnp.inf)
+        def set_bit(i, t):
+            cand = t | (sign >> i.astype(jnp.uint32))
+            enough = jnp.sum(key >= cand[:, None], axis=-1) >= k
+            return jnp.where(enough, cand, t)
+
+        t = jax.lax.fori_loop(0, 32, set_bit,
+                              jnp.zeros(z.shape[:1], jnp.uint32))
+        thr = jax.lax.bitcast_convert_type(
+            jnp.where(t >= sign, t ^ sign, ~t), jnp.float32)
+        return jnp.where(top_k > 0, thr, -jnp.inf)
+
+    def untruncated(z, top_k):
+        return jnp.full(z.shape[:1], -jnp.inf, jnp.float32)
+
+    return jax.lax.cond(jnp.any(top_k > 0), bisect, untruncated, z, top_k)
 
 
 def topk_mask_sample_forward(logits, temperature, top_k, u, *,
@@ -170,9 +182,8 @@ def topk_mask_sample_forward(logits, temperature, top_k, u, *,
     (the device sampling pipeline's warp step).
 
     logits: (S, V); temperature: (S,) — ``<= 0`` means greedy argmax;
-    top_k: (S,) int32 (0 = no truncation) or ``None`` when no row in the
-    batch truncates (skips the threshold entirely — the common greedy
-    / pure-temperature serving case); u: (S,) keyed uniforms in [0, 1).
+    top_k: (S,) int32 (0 = no truncation; ``None`` is all zeros); u: (S,)
+    keyed uniforms in [0, 1).
     Returns ``tokens (S,) int32`` (plus the warped ``probs (S, V)`` when
     ``return_probs`` — the speculative draft phase keeps it as ``q``).
 
@@ -184,16 +195,12 @@ def topk_mask_sample_forward(logits, temperature, top_k, u, *,
     temperature = jnp.asarray(temperature, jnp.float32)
     u = jnp.asarray(u, jnp.float32)
     if top_k is None:
-        threshold = None                       # no row truncates: no
-    else:                                      # threshold, no masking pass
-        z = (logits.astype(jnp.float32)
-             / jnp.maximum(temperature, 1e-30)[:, None])
-        threshold = topk_threshold(z, top_k)
+        top_k = jnp.zeros(logits.shape[:1], jnp.int32)
+    z = logits.astype(jnp.float32) / jnp.maximum(temperature, 1e-30)[:, None]
+    threshold = topk_threshold(z, top_k)
     run, interp = _mode(use_pallas)
     if run:
-        thr = (threshold if threshold is not None
-               else jnp.full(logits.shape[:1], -jnp.inf, jnp.float32))
-        return topk_mask_sample(logits, temperature, thr, u,
+        return topk_mask_sample(logits, temperature, threshold, u,
                                 return_probs=return_probs,
                                 interpret=interp)
     tokens, probs = ref.topk_mask_sample_ref(logits, temperature, threshold,

@@ -8,20 +8,27 @@ pool without ever materializing a contiguous (B, T) cache. The MB axis is
 sequential per sequence; softmax runs in streaming (flash) form with running
 (max, denom, acc) scratch carried across blocks, and blocks past
 ``context_len`` are skipped entirely (their DMA still targets a valid pool
-slot — the shared null block 0 — so the index map stays in bounds).
+slot — the shared null block 0 — so the index map stays in bounds). The MB
+axis is bounded at run time by the longest context in the call (a dynamic
+grid bound), so a table wider than every live sequence costs no grid steps:
+the serving steps pass one table width whatever the sequences' lengths.
 
 ``paged_prefill_attention`` (mixed chunked-prefill/decode iterations): grid
-(T, MB) over a *flat token batch* — several tokens may belong to the same
-sequence (a prefill chunk) while others are single decode tokens of other
-sequences. A third scalar-prefetch operand, ``slot_ids``, maps each token to
-its block-table row; per-token ``context_lens`` (= position + 1) express
-intra-chunk causality, because the chunk's own K/V is scattered into the
-pool before the kernel runs.
+(T, MB), bounded the same way, over a *flat token batch* — several tokens
+may belong to the same sequence (a prefill chunk) while others are single
+decode tokens of other sequences. A third scalar-prefetch operand,
+``slot_ids``, maps each token to its block-table row; per-token
+``context_lens`` (= position + 1) express intra-chunk causality, because the
+chunk's own K/V is scattered into the pool before the kernel runs.
 
-Tiling: the q block is (1, Hq, D) and each K/V block (1, BS, Hkv, D); their
-last two dims span the array axes, which Mosaic accepts at any size, so no
-padding is needed (``tests/test_tpu_compile.py`` compiles both kernels for a
-v5e at gpt2-small widths). Tests validate numerics via interpret mode
+Layout: the pools are lane-dense, (NB, BS, Hkv * D): a token's K (or V)
+for every kv head is one row, kv head h at lanes [h*D, (h+1)*D), which the
+body takes as a static lane slice. The q block is (1, Hq, D) and each K/V
+block (1, BS, Hkv * D); their last two dims span the array axes, which
+Mosaic accepts at any size. A (NB, BS, Hkv, D) pool cost a relayout copy of
+the whole pool into and out of every layer (docs/kernels.md).
+``tests/test_tpu_compile.py`` compiles both kernels for a v5e and checks the
+compiled step for pool copies; tests validate numerics via interpret mode
 against ``ref.paged_attention_ref``.
 """
 from __future__ import annotations
@@ -54,14 +61,16 @@ def _flash_body(ctx, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(j * bs < ctx)
     def _block():
         q = q_ref[0].astype(jnp.float32)                 # (Hq, D)
-        k = k_ref[0].astype(jnp.float32)                 # (BS, Hkv, D)
-        v = v_ref[0].astype(jnp.float32)
         hq, d = q.shape
-        hkv = k.shape[1]
+        hkv = hq // groups
+        # kv head h is lanes [h*D, (h+1)*D) of each (BS, Hkv*D) row
+        k, v = (jnp.stack([r[0, :, h * d:(h + 1) * d].astype(jnp.float32)
+                           for h in range(hkv)])     # (Hkv, BS, D)
+                for r in (k_ref, v_ref))
         qg = (q * (1.0 / math.sqrt(d))).reshape(hkv, groups, d)
         # (Hkv, G, BS) logits via per-kv-head batched contraction
         logits = jax.lax.dot_general(
-            qg, jnp.moveaxis(k, 0, 1),                   # (Hkv, BS, D)
+            qg, k,
             dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         if softcap and softcap > 0.0:
@@ -78,7 +87,7 @@ def _flash_body(ctx, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[...] = m_new
         pv = jax.lax.dot_general(
-            p.reshape(hkv, groups, bs), jnp.moveaxis(v, 0, 1),
+            p.reshape(hkv, groups, bs), v,
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)          # (Hkv, G, D)
         acc_ref[...] = acc_ref[...] * alpha + pv.reshape(hq, d)
@@ -86,6 +95,20 @@ def _flash_body(ctx, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(j == mb - 1)
     def _finalize():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _groups(hq: int, d: int, row: int) -> int:
+    """Query heads per kv head, from q's (Hq, D) and a pool row of Hkv * D."""
+    hkv = row // d
+    assert hkv * d == row and hq % hkv == 0, (hq, d, row)
+    return hq // hkv
+
+
+def _live_blocks(context_lens, bs: int, mb: int):
+    """The grid's block axis, set at run time: the blocks of the longest
+    context in the call, at least one and at most the table's width."""
+    n = (jnp.max(context_lens.astype(jnp.int32)) + bs - 1) // bs
+    return jnp.clip(n, 1, mb)
 
 
 def _kernel(block_tables_ref, context_lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -104,9 +127,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     Contract (see docs/kernels.md for the full operand walkthrough):
 
     * ``q``: (B, Hq, D) — the decode batch's current tokens.
-    * ``k_pool`` / ``v_pool``: (NB, BS, Hkv, D) — global block pools; a
-      sequence's K/V lives at the (non-contiguous) blocks its table names.
-      Hq must be a multiple of Hkv (grouped-query heads).
+    * ``k_pool`` / ``v_pool``: (NB, BS, Hkv * D) — global block pools,
+      lane-dense (kv head h is lanes ``[h*D, (h+1)*D)`` of a token's row);
+      a sequence's K/V lives at the (non-contiguous) blocks its table
+      names. Hq must be a multiple of Hkv (grouped-query heads).
     * ``block_tables``: (B, MB) int32 — scalar-prefetch operand; entry
       ``[i, j]`` is the pool slot of sequence ``i``'s ``j``-th block.
       Unused entries must point at a valid pool slot (the shared null
@@ -116,24 +140,24 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
       softmax), so stale data in reused blocks is harmless.
     * ``softcap`` > 0 applies ``softcap * tanh(logits / softcap)``.
 
-    Grid is (B, MB), MB innermost and sequential per sequence: streaming
-    (flash) softmax over blocks with float32 running (max, denom, acc)
-    scratch. Returns (B, Hq, D) in ``q``'s dtype. Prefer calling through
+    Grid is (B, n), n the blocks of the longest context (at most MB),
+    innermost and sequential per sequence: streaming (flash) softmax over
+    blocks with float32 running (max, denom, acc) scratch. Returns
+    (B, Hq, D) in ``q``'s dtype. Prefer calling through
     ``ops.paged_attention_forward`` — it owns the ref/Pallas/interpret
     dispatch."""
     b, hq, d = q.shape
-    _, bs, hkv, _ = k_pool.shape
+    _, bs, row = k_pool.shape
     mb = block_tables.shape[1]
-    groups = hq // hkv
-    assert groups * hkv == hq, (hq, hkv)
+    groups = _groups(hq, d, row)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, mb),
+        grid=(b, _live_blocks(context_lens, bs, mb)),
         in_specs=[
             pl.BlockSpec((1, hq, d), lambda i, j, bt, cl: (i, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d), lambda i, j, bt, cl: (bt[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d), lambda i, j, bt, cl: (bt[i, j], 0, 0, 0)),
+            pl.BlockSpec((1, bs, row), lambda i, j, bt, cl: (bt[i, j], 0, 0)),
+            pl.BlockSpec((1, bs, row), lambda i, j, bt, cl: (bt[i, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, hq, d), lambda i, j, bt, cl: (i, 0, 0)),
         scratch_shapes=[
@@ -187,26 +211,26 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
       kernel runs; token ``i`` of a chunk then sees exactly its prefix.
     * ``softcap`` as in ``paged_attention``.
 
-    Grid is (T, MB); the block-table row is resolved through
-    ``slot_ids`` inside the BlockSpec index maps, so the body is the same
-    streaming-softmax step as the decode kernel (``_flash_body``).
+    Grid is (T, n), n as in ``paged_attention``; the block-table row is
+    resolved through ``slot_ids`` inside the BlockSpec index maps, so the
+    body is the same streaming-softmax step as the decode kernel
+    (``_flash_body``).
     Returns (T, Hq, D). Prefer ``ops.paged_prefill_attention_forward``
     for dispatch."""
     t, hq, d = q.shape
-    _, bs, hkv, _ = k_pool.shape
+    _, bs, row = k_pool.shape
     mb = block_tables.shape[1]
-    groups = hq // hkv
-    assert groups * hkv == hq, (hq, hkv)
+    groups = _groups(hq, d, row)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(t, mb),
+        grid=(t, _live_blocks(context_lens, bs, mb)),
         in_specs=[
             pl.BlockSpec((1, hq, d), lambda i, j, sid, bt, cl: (i, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d),
-                         lambda i, j, sid, bt, cl: (bt[sid[i], j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d),
-                         lambda i, j, sid, bt, cl: (bt[sid[i], j], 0, 0, 0)),
+            pl.BlockSpec((1, bs, row),
+                         lambda i, j, sid, bt, cl: (bt[sid[i], j], 0, 0)),
+            pl.BlockSpec((1, bs, row),
+                         lambda i, j, sid, bt, cl: (bt[sid[i], j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, hq, d), lambda i, j, sid, bt, cl: (i, 0, 0)),
         scratch_shapes=[

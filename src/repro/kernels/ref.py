@@ -41,7 +41,8 @@ def paged_attention_ref(q, k_pool, v_pool, block_tables, context_lens, *,
     """Decode attention over a block-paged KV cache (gather + plain softmax).
 
     q: (B, Hq, D) — one query token per sequence, pre-RoPE'd.
-    k_pool/v_pool: (NB, BS, Hkv, D) — global block pools.
+    k_pool/v_pool: (NB, BS, Hkv * D) — global block pools, lane-dense (kv
+    head h is lanes [h*D, (h+1)*D) of a token's row).
     block_tables: (B, MB) int32 — per-sequence block ids (0 = null block).
     context_lens: (B,) int32 — valid tokens per sequence (incl. current).
 
@@ -51,7 +52,8 @@ def paged_attention_ref(q, k_pool, v_pool, block_tables, context_lens, *,
     """
     import math
     b, hq, d = q.shape
-    nb, bs, hkv, _ = k_pool.shape
+    nb, bs, row = k_pool.shape
+    hkv = row // d
     mb = block_tables.shape[1]
     g = hq // hkv
     k = jnp.take(k_pool, block_tables, axis=0).reshape(b, mb * bs, hkv, d)

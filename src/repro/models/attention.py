@@ -219,7 +219,8 @@ def paged_attn_apply(
     (continuous batching: sequences in the batch are at different lengths).
     ``positions``: (B,) int32 — 0-based index of the current token; its K/V is
     scattered into (block_tables[b, pos // BS], pos % BS) before attending
-    over the ``pos + 1`` valid keys. Returns (y, k_pool, v_pool).
+    over the ``pos + 1`` valid keys. Pools are lane-dense (NB, BS, Hkv * D)
+    (see ``serving.kv_cache``). Returns (y, k_pool, v_pool).
     """
     r = ranks or {}
     hd = cfg.resolved_head_dim
@@ -230,8 +231,11 @@ def paged_attn_apply(
 
     blk = jnp.take_along_axis(block_tables, (positions // bs)[:, None], axis=1)[:, 0]
     off = positions % bs
-    k_pool = k_pool.at[blk, off].set(k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, off].set(v[:, 0].astype(v_pool.dtype))
+    # lane-dense pool rows: (B, Hkv, D) -> (B, Hkv * D)
+    k_pool = k_pool.at[blk, off].set(
+        k[:, 0].reshape(bsz, -1).astype(k_pool.dtype))
+    v_pool = v_pool.at[blk, off].set(
+        v[:, 0].reshape(bsz, -1).astype(v_pool.dtype))
 
     from repro.kernels import ops
     out = ops.paged_attention_forward(
@@ -281,9 +285,10 @@ def paged_prefill_attn_apply(
     blk = block_tables[slot_ids, positions // bs]                   # (T,)
     off = positions % bs
     # distinct (slot, pos) pairs -> distinct (blk, off) targets; pads all
-    # write identical values to the null block, so duplicates are benign
-    k_pool = k_pool.at[blk, off].set(k[0].astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, off].set(v[0].astype(v_pool.dtype))
+    # write identical values to the null block, so duplicates are benign.
+    # The pool's rows are lane-dense: (T, Hkv, D) -> (T, Hkv * D)
+    k_pool = k_pool.at[blk, off].set(k[0].reshape(t, -1).astype(k_pool.dtype))
+    v_pool = v_pool.at[blk, off].set(v[0].reshape(t, -1).astype(v_pool.dtype))
 
     from repro.kernels import ops
     out = ops.paged_prefill_attention_forward(

@@ -715,7 +715,7 @@ def paged_decode_step(
 
     tokens: (B, 1). ``caches``: {'positions': (B,) current 0-based token
     index per sequence, 'block_tables': (B, MB), 'segments': [{'k': (count,
-    NB, BS, Hkv, D), 'v': ...} per segment]}. Unlike ``decode_step`` there is
+    NB, BS, Hkv * D), 'v': ...} per segment]}. Unlike ``decode_step`` there is
     no shared scalar position — every sequence sits at its own length, which
     is what lets new requests join mid-decode. Returns (logits (B, 1, V),
     new caches with K/V scattered into each sequence's blocks).
@@ -763,7 +763,7 @@ def paged_mixed_step(
       {'slot_ids':  (T,) block-table row per token (pads -> a null row),
        'positions': (T,) 0-based position of each token in its sequence,
        'block_tables': (B(+null rows), MB),
-       'segments': [{'k': (count, NB, BS, Hkv, D), 'v': ...} per segment],
+       'segments': [{'k': (count, NB, BS, Hkv * D), 'v': ...} per segment],
        'sample_ids': optional (S,) flat-token indices to score}
 
     Each token's K/V is scattered into its slot's blocks, then it attends

@@ -801,12 +801,18 @@ class ElasticEngine:
             cache.tracer = self.tracer
             batcher = ContinuousBatcher(self.max_batch)
         self._live.update(row=row, cache=cache, batcher=batcher, spec=False)
-        if self.lookahead and self.device_sampling:
-            self._serve_row_pipelined(row, params, sched, cache, batcher,
-                                      metrics, results)
-        else:
-            self._serve_row_sync(row, params, sched, cache, batcher,
-                                 metrics, results)
+        try:
+            if self.lookahead and self.device_sampling:
+                self._serve_row_pipelined(row, params, sched, cache, batcher,
+                                          metrics, results)
+            else:
+                self._serve_row_sync(row, params, sched, cache, batcher,
+                                     metrics, results)
+        finally:
+            # the row drained: free its pools now, not when the cyclic GC
+            # reaches the cache (its allocator's evict hook points back at
+            # it), so a later row's pools never sit beside them
+            cache.pools = []
 
     def _apply_cancellations(self, sched, cache, batcher, plog) -> int:
         """Apply every uncommitted cancellation-log entry: a waiting request
@@ -1465,9 +1471,11 @@ class ElasticEngine:
     def _pack_sampling(metas, width: int) -> Dict:
         """Device-sampling operands for ``width`` gathered rows. ``metas``:
         one ``(sampler, purpose, position)`` per live row, aligned with
-        ``sample_ids``. Greedy rows carry temperature 0 (in-jit argmax);
-        ``top_k`` collapses to None when no row truncates so the common
-        case never pays the top-k threshold (a distinct jit trace)."""
+        ``sample_ids``. Greedy rows carry temperature 0 (in-jit argmax) and
+        top-k 0 (no truncation). ``top_k`` is an array even when no row
+        truncates: a None there is a second program for every step shape,
+        compiled where a batch first samples only greedy rows. Such a batch
+        skips the top-k threshold at run time (``ops.topk_threshold``)."""
         temp = np.zeros(width, np.float32)
         topk = np.zeros(width, np.int32)
         seed = np.zeros(width, np.int32)
@@ -1482,7 +1490,7 @@ class ElasticEngine:
             pos[i] = p
         return {
             "temperature": jnp.asarray(temp),
-            "top_k": jnp.asarray(topk) if topk.any() else None,
+            "top_k": jnp.asarray(topk),
             "seed": jnp.asarray(seed), "req_id": jnp.asarray(req),
             "purpose": jnp.asarray(purpose), "position": jnp.asarray(pos),
         }
@@ -1506,8 +1514,7 @@ class ElasticEngine:
         caches = {
             "slot_ids": jnp.asarray(sid),
             "positions": jnp.asarray(pos),
-            "block_tables": cache.device_tables(cache.active_max_blocks(),
-                                                null_rows=1),
+            "block_tables": cache.device_tables(null_rows=1),
             "segments": cache.pools,
             "sample_ids": jnp.asarray(self._pack_sample_ids(sample_ids,
                                                             rows)),

@@ -3,9 +3,13 @@ layer, a host-side refcounted allocator with automatic prefix caching, and
 per-slot block tables.
 
 Memory layout (vLLM-style, adapted to scanned segments): every attention
-segment owns K/V pools shaped (count, num_blocks, block_size, Hkv, hd) —
+segment owns K/V pools shaped (count, num_blocks, block_size, Hkv * hd) —
 ``count`` stacked layers share one *block id space*, so a sequence holds one
-block table that addresses the same slots in every layer's pool. Block 0 is
+block table that addresses the same slots in every layer's pool. A token's
+K (or V) for every kv head is one lane-dense row (head h at lanes
+[h*hd, (h+1)*hd)): with a trailing (Hkv, hd) pair the TPU compiler lays the
+block axis on the lanes, and the step's scatter and kernels then copied the
+whole pool in and out of every layer to row-major and back. Block 0 is
 the reserved null block: it backs unused table entries and idle batch slots,
 so device-side gathers never index out of bounds.
 
@@ -328,14 +332,14 @@ class PagedKVCache:
         self.max_len = max_len
         self.block_size = block_size
         self.max_blocks_per_seq = math.ceil(max_len / block_size)
-        # pow2 ceiling of the table width: the widest shape jit may see.
-        # active_max_blocks buckets into {1, 2, 4, ..., padded} so every
-        # width is a bucketing fixed point — no surprise late recompiles
-        # when max_blocks_per_seq itself is not a power of two.
+        # the one table width every step dispatches (the pow2 ceiling of
+        # max_blocks_per_seq): the paged kernels bound their block axis at
+        # run time by the longest context in the call, so columns past it
+        # cost nothing, and a single width is a single program per token
+        # width and sample-row bucket, never a new one mid-serve
         self.padded_max_blocks = 1
         while self.padded_max_blocks < self.max_blocks_per_seq:
             self.padded_max_blocks *= 2
-        self._seen_widths: set = set()
         if num_blocks is None:
             num_blocks = max_batch * self.max_blocks_per_seq
         if prefix_cache is None:
@@ -347,7 +351,7 @@ class PagedKVCache:
         self.pools = []
         for seg in cfg.segments:
             shape = (seg.count, num_blocks + 1, block_size,
-                     cfg.num_kv_heads, hd)
+                     cfg.num_kv_heads * hd)
             self.pools.append({"k": jnp.zeros(shape, dtype),
                                "v": jnp.zeros(shape, dtype)})
         self.slots: List[Optional[SlotState]] = [None] * max_batch
@@ -719,35 +723,28 @@ class PagedKVCache:
 
     # ------------------------------------------------------------ device
 
-    def host_tables(self, max_blocks: Optional[int] = None, *,
-                    null_rows: int = 0) -> np.ndarray:
+    def host_tables(self, *, null_rows: int = 0) -> np.ndarray:
         """Host-side copy of the block tables (see ``device_tables``) — for
         callers that dispatch several forwards against one table snapshot
         (donated device uploads cannot be reused across dispatches)."""
-        if max_blocks is None:
-            t = self._tables
-        elif max_blocks <= self._tables.shape[1]:
-            t = self._tables[:, :max_blocks]
-        else:
-            # pow2-padded width past the physical table: pad with null
-            # blocks (positions never reach them — they exist only so the
-            # widest jit shape is a bucketing fixed point)
-            pad = np.full((self.max_batch, max_blocks - self._tables.shape[1]),
+        t = self._tables
+        if self.padded_max_blocks > t.shape[1]:
+            # the pow2-padded width past the physical table: pad with null
+            # blocks (positions never reach them)
+            pad = np.full((self.max_batch, self.padded_max_blocks - t.shape[1]),
                           NULL_BLOCK, np.int32)
-            t = np.concatenate([self._tables, pad], axis=1)
+            t = np.concatenate([t, pad], axis=1)
         if null_rows:
             t = np.concatenate(
                 [t, np.full((null_rows, t.shape[1]), NULL_BLOCK, np.int32)])
         return t
 
-    def device_tables(self, max_blocks: Optional[int] = None, *,
-                      null_rows: int = 0) -> jax.Array:
-        """Block tables, optionally truncated to ``max_blocks`` columns —
-        attention cost then scales with the longest *live* context instead
-        of ``max_len`` (the whole point of paging). ``null_rows`` appends
-        rows of null blocks: the mixed-iteration path points pad tokens at
-        such a row so their reads/writes never touch a live sequence."""
-        return jnp.asarray(self.host_tables(max_blocks, null_rows=null_rows))
+    def device_tables(self, *, null_rows: int = 0) -> jax.Array:
+        """Block tables, ``padded_max_blocks`` wide whatever the live
+        lengths. ``null_rows`` appends rows of null blocks: the
+        mixed-iteration path points pad tokens at such a row so their
+        reads/writes never touch a live sequence."""
+        return jnp.asarray(self.host_tables(null_rows=null_rows))
 
     def device_positions(self) -> jax.Array:
         """(B,) 0-based index of the token being decoded this step per slot.
@@ -761,32 +758,11 @@ class PagedKVCache:
                for s in self.slots]
         return jnp.asarray(np.asarray(pos, np.int32))
 
-    def model_caches(self, max_blocks: Optional[int] = None) -> Dict:
+    def model_caches(self) -> Dict:
         """Cache pytree consumed by ``transformer.paged_decode_step``."""
         return {"positions": self.device_positions(),
-                "block_tables": self.device_tables(max_blocks),
+                "block_tables": self.device_tables(),
                 "segments": self.pools}
-
-    def active_max_blocks(self) -> int:
-        """Smallest power-of-two table width covering every live sequence
-        (so jit sees O(log max_blocks_per_seq) distinct shapes). Clamped to
-        the pow2-*padded* table width, never the raw ``max_blocks_per_seq``:
-        clamping to a non-pow2 bound used to introduce one extra jit shape
-        the first time the longest sequences filled their tables — a
-        surprise recompile mid-serve."""
-        used = max((len(s.blocks) for s in self.slots if s is not None),
-                   default=1)
-        mb = 1
-        while mb < used:
-            mb *= 2
-        mb = min(mb, self.padded_max_blocks)
-        self._seen_widths.add(mb)
-        # every observed width must be a fixed point of the bucketing —
-        # i.e. a pow2 no larger than the padded cap — or jit shape count
-        # stops being O(log max_blocks_per_seq)
-        assert all(w == min(1 << (w - 1).bit_length(), self.padded_max_blocks)
-                   for w in self._seen_widths), self._seen_widths
-        return mb
 
     def update_pools(self, new_caches: Dict) -> None:
         self.pools = [dict(p) for p in new_caches["segments"]]
@@ -812,7 +788,8 @@ class PagedKVCache:
                 count, s_pad = src.shape[0], src.shape[1]
                 nb = s_pad // self.block_size
                 assert nb == len(st.blocks), (nb, len(st.blocks))
-                src = src.reshape(count, nb, self.block_size, *src.shape[2:])
+                # (count, S_pad, H, D) -> blocks of lane-dense rows
+                src = src.reshape(count, nb, self.block_size, -1)
                 self.pools[si][name] = (
                     self.pools[si][name].at[:, idx].set(src))
 

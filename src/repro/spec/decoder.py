@@ -271,9 +271,17 @@ class SpecDecoder:
     # -------------------------------------------------------------- loop
 
     def serve(self) -> None:
+        self.engine._live.update(row=self.row, cache=self.cache,
+                                 batcher=self.batcher, spec=True)
+        try:
+            self._serve_rounds()
+        finally:
+            # the row drained: free its pools now, as
+            # ElasticEngine._serve_row_mixed does
+            self.cache.pools = []
+
+    def _serve_rounds(self) -> None:
         eng, sched, tr = self.engine, self.sched, self.tracer
-        eng._live.update(row=self.row, cache=self.cache,
-                         batcher=self.batcher, spec=True)
         while True:
             it0 = self.metrics.now()
             self._disp_s = 0.0
@@ -328,8 +336,7 @@ class SpecDecoder:
             # so one table snapshot serves all k+1 dispatches (host-side:
             # the jitted forwards donate their cache operand, so a device
             # copy could not be reused across dispatches)
-            self._round_tables = self.cache.host_tables(
-                self.cache.active_max_blocks(), null_rows=1)
+            self._round_tables = self.cache.host_tables(null_rows=1)
             if eng.device_sampling:
                 self._draft_phase_device(plans)
                 draft_end = self.metrics.now()

@@ -129,6 +129,23 @@ def test_greedy_rows_are_raw_argmax():
     np.testing.assert_array_equal(toks, np.argmax(logits, axis=-1))
 
 
+def test_all_greedy_batch_shares_the_sampling_program():
+    """A batch whose sampled rows are all greedy packs the same operand
+    tree as one with top-k rows, so the engine dispatches one step program
+    per shape instead of compiling a second where a batch first samples
+    only greedy rows."""
+    greedy = [(SamplerState(None, 0), DRAW_TARGET, 5)]
+    topk = greedy + [(SamplerState(SamplingParams(temperature=0.8, top_k=40,
+                                                  seed=3), 1),
+                      DRAW_TARGET, 9)]
+    a = ElasticEngine._pack_sampling(greedy, 8)
+    b = ElasticEngine._pack_sampling(topk, 8)
+    assert (jax.tree.structure(a) == jax.tree.structure(b))
+    assert [(x.shape, x.dtype) for x in jax.tree.leaves(a)] == \
+        [(x.shape, x.dtype) for x in jax.tree.leaves(b)]
+    np.testing.assert_array_equal(np.asarray(a["top_k"]), 0)
+
+
 # ----------------------------------------------- draw-equivalence (seeded)
 
 def test_device_draws_match_host_distribution():

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, paged_attention, ref
 
 RNG = np.random.default_rng(0)
 
@@ -101,8 +101,9 @@ if _env_bs:
 
 def _paged_pools(b, hkv, d, bs, mb, dtype):
     nb = b * mb + 1
-    kp = jnp.asarray(RNG.standard_normal((nb, bs, hkv, d)), dtype)
-    vp = jnp.asarray(RNG.standard_normal((nb, bs, hkv, d)), dtype)
+    # lane-dense pools, as ``PagedKVCache`` allocates them
+    kp = jnp.asarray(RNG.standard_normal((nb, bs, hkv * d)), dtype)
+    vp = jnp.asarray(RNG.standard_normal((nb, bs, hkv * d)), dtype)
     tables = 1 + RNG.permutation(b * mb).reshape(b, mb).astype(np.int32)
     return kp, vp, jnp.asarray(tables)
 
@@ -142,6 +143,37 @@ def test_paged_prefill_kernel_parity_sweep(hq, hkv, d, bs, mb, dtype):
     err = float(jnp.abs(y_ref.astype(jnp.float32)
                         - y_ker.astype(jnp.float32)).max())
     assert err < tol, (err, (hq, hkv, d, bs, mb))
+
+
+@pytest.mark.parametrize("lens,mb,want", [([0, 0], 8, 1), ([1, 5], 8, 2),
+                                           ([4, 17], 8, 5), ([3, 99], 8, 8)])
+def test_live_blocks_bounds_the_block_axis(lens, mb, want):
+    """The kernels' block axis walks the longest context's blocks: at least
+    one, at most the table's width."""
+    got = paged_attention._live_blocks(jnp.asarray(lens, jnp.int32), 4, mb)
+    assert int(got) == want
+
+
+@pytest.mark.parametrize("prefill", [False, True])
+def test_paged_kernels_wide_table_is_free(prefill):
+    """Null columns past every live context change nothing: the kernel
+    output with a table four times wider is bitwise the narrow one's."""
+    b, hq, hkv, d, bs, mb = 3, 8, 4, 16, 4, 5
+    kp, vp, tables = _paged_pools(b, hkv, d, bs, mb, jnp.float32)
+    wide = jnp.pad(tables, ((0, 0), (0, 3 * mb)))
+    if prefill:
+        q = jnp.asarray(RNG.standard_normal((6, hq, d)), jnp.float32)
+        sid = jnp.asarray([0, 0, 1, 2, 2, 2], jnp.int32)
+        lens = jnp.asarray([3, 4, 20, 9, 10, 11], jnp.int32)
+        run = lambda t: paged_attention.paged_prefill_attention(
+            q, kp, vp, t, sid, lens, interpret=True)
+    else:
+        q = jnp.asarray(RNG.standard_normal((b, hq, d)), jnp.float32)
+        lens = jnp.asarray([3, 20, 11], jnp.int32)
+        run = lambda t: paged_attention.paged_attention(
+            q, kp, vp, t, lens, interpret=True)
+    np.testing.assert_array_equal(np.asarray(run(tables)),
+                                  np.asarray(run(wide)))
 
 
 def test_paged_prefill_reduces_to_decode_and_respects_window():
@@ -275,6 +307,18 @@ def test_topk_threshold_matches_sort(v):
     got = ops.topk_threshold(jnp.asarray(z), jnp.asarray(topks))
     want = ref.topk_threshold_ref(jnp.asarray(z), jnp.asarray(topks))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_topk_threshold_untruncated_batch_is_minus_inf():
+    """With no row truncating, the run-time branch skips the bisection and
+    gives the oracle's cutoff, -inf on every row."""
+    z = jnp.asarray(np.random.default_rng(0).standard_normal((5, 33)),
+                    jnp.float32)
+    topks = jnp.zeros(5, jnp.int32)
+    got = ops.topk_threshold(z, topks)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.topk_threshold_ref(z, topks)))
+    assert np.isneginf(np.asarray(got)).all()
 
 
 def test_sampling_dispatch_matches_host_oracle():

@@ -8,8 +8,8 @@ Covers:
     infeasible (the old integer ``+ 1`` slack admitted it on fine tables);
   * incremental fragmentation parity — ``fragmentation()`` from the run
     tracker must equal the sorted-scan reference after any op sequence;
-  * ``active_max_blocks`` pow2 closure — observed jit table widths must be
-    bucketing fixed points even when ``max_blocks_per_seq`` is not pow2;
+  * one table width — every step dispatches the pow2-padded table, even
+    when ``max_blocks_per_seq`` is not pow2 and every live sequence is short;
   * allocator refcount rules — no block recycled at refcount > 0, warm-tier
     FIFO eviction through the hook, ``take`` resurrection;
   * COW block copies are bit-exact on device and never disturb the sharer;
@@ -90,12 +90,12 @@ def test_fragmentation_incremental_parity_walk():
     assert a.fragmentation() == a.fragmentation_exact() == 0.0
 
 
-# ------------------------------------------- active_max_blocks pow2 clamp
+# -------------------------------------------------- one table width
 
 def test_active_max_blocks_pow2_closure_non_pow2_cap():
-    """max_len/block_size = 6 blocks (not pow2): widths must bucket into
-    {1, 2, 4, 8}, never clamp to the raw 6 — that used to add one surprise
-    jit shape when the longest sequences filled their tables."""
+    """max_len/block_size = 6 blocks (not pow2): the one table width the
+    steps dispatch is the pow2 ceiling, 8, whatever the live lengths, never
+    the raw 6; its extra columns are null blocks."""
     cache = PagedKVCache(CFG_TINY, max_batch=1, max_len=44, block_size=8)
     assert cache.max_blocks_per_seq == 6
     assert cache.padded_max_blocks == 8
@@ -103,13 +103,36 @@ def test_active_max_blocks_pow2_closure_non_pow2_cap():
     widths = set()
     while cache.slots[0].num_tokens < 44:
         cache.extend_slot(0, min(8, 44 - cache.slots[0].num_tokens))
-        widths.add(cache.active_max_blocks())
-    assert widths <= {1, 2, 4, 8}
-    assert 6 not in widths
-    assert cache.active_max_blocks() == 8    # full table pads, not clamps
-    t = cache.host_tables(8)
+        widths.add(cache.host_tables().shape[1])
+    assert widths == {8}
+    t = cache.host_tables()
     assert t.shape == (1, 8)
+    assert t[:, :6].all()                    # a full table
     assert not t[:, 6:].any()                # padded columns are null blocks
+
+
+@pytest.mark.parametrize("spec", [None, SpecConfig(draft_rank=0.7, spec_len=3)],
+                         ids=["plain", "spec"])
+def test_every_step_dispatches_one_table_width(smoke_state, monkeypatch, spec):
+    """Short and long live sequences alike get the padded table width: a
+    narrower width would be one more program per token width and row
+    bucket, compiled mid-serve the first time a near-empty server met it."""
+    widths = []
+    host_tables = PagedKVCache.host_tables
+
+    def record(self, **kw):
+        t = host_tables(self, **kw)
+        widths.append(t.shape[1])
+        return t
+
+    monkeypatch.setattr(PagedKVCache, "host_tables", record)
+    eng = _mk_engine(smoke_state, prefill_chunk=BLOCK, spec=spec)
+    rng = np.random.default_rng(3)
+    reqs = [Request(prompt=rng.integers(0, CFG_TINY.vocab_size, n)
+                    .astype(np.int32), max_new_tokens=4, budget=1.0)
+            for n in (3, 40, 5)]
+    eng.generate(reqs, mode="continuous")
+    assert widths and set(widths) == {64 // BLOCK}
 
 
 # ------------------------------------------------ allocator refcount rules

@@ -84,23 +84,28 @@ def test_cache_scatter_roundtrip():
     seg_caches = [{"k": jnp.asarray(vals), "v": jnp.asarray(vals) * 2.0}
                   for _ in cfg.segments]
     c.write_prefill(0, seg_caches)
-    pool_k = np.asarray(c.pools[0]["k"])     # (count, NB, BS, H, D)
+    pool_k = np.asarray(c.pools[0]["k"])     # (count, NB, BS, H * D)
+    assert pool_k.shape[2:] == (4, cfg.num_kv_heads * hd)
     for t in range(8):
         blk, off = st.blocks[t // 4], t % 4
-        np.testing.assert_array_equal(pool_k[:, blk, off], vals[:, 0, t])
+        np.testing.assert_array_equal(pool_k[:, blk, off],
+                                      vals[:, 0, t].reshape(count, -1))
 
 
 # ------------------------------------------------------- paged attn kernel
 
+# pool rows of Hkv * D lanes: 64, 64, 8, and 144 (a head straddles the
+# 128-lane boundary)
 @pytest.mark.parametrize("b,hq,hkv,d,bs,mb", [(2, 4, 4, 16, 4, 3),
                                               (3, 8, 2, 32, 8, 4),
-                                              (1, 2, 1, 8, 16, 2)])
+                                              (1, 2, 1, 8, 16, 2),
+                                              (2, 6, 3, 48, 8, 3)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_kernel_vs_ref(b, hq, hkv, d, bs, mb, dtype):
     nb = b * mb + 1
     q = jnp.asarray(RNG.standard_normal((b, hq, d)), dtype)
-    kp = jnp.asarray(RNG.standard_normal((nb, bs, hkv, d)), dtype)
-    vp = jnp.asarray(RNG.standard_normal((nb, bs, hkv, d)), dtype)
+    kp = jnp.asarray(RNG.standard_normal((nb, bs, hkv * d)), dtype)
+    vp = jnp.asarray(RNG.standard_normal((nb, bs, hkv * d)), dtype)
     tables = 1 + RNG.permutation(b * mb).reshape(b, mb).astype(np.int32)
     lens = RNG.integers(1, mb * bs + 1, size=b).astype(np.int32)
     y_ref = ops.paged_attention_forward(q, kp, vp, jnp.asarray(tables),
@@ -118,8 +123,8 @@ def test_paged_attention_softcap_and_ignores_dead_blocks():
     b, hq, hkv, d, bs, mb = 2, 4, 2, 16, 4, 3
     nb = b * mb + 1
     q = jnp.asarray(RNG.standard_normal((b, hq, d)).astype(np.float32))
-    kp = jnp.asarray(RNG.standard_normal((nb, bs, hkv, d)).astype(np.float32))
-    vp = jnp.asarray(RNG.standard_normal((nb, bs, hkv, d)).astype(np.float32))
+    kp = jnp.asarray(RNG.standard_normal((nb, bs, hkv * d)).astype(np.float32))
+    vp = jnp.asarray(RNG.standard_normal((nb, bs, hkv * d)).astype(np.float32))
     tables = 1 + RNG.permutation(b * mb).reshape(b, mb).astype(np.int32)
     lens = np.asarray([5, 8], np.int32)     # block 2 dead for both
     y1 = ops.paged_attention_forward(q, kp, vp, jnp.asarray(tables),
@@ -139,8 +144,8 @@ def test_paged_ref_matches_contiguous_attention():
     import math
     b, hq, hkv, d, bs, mb = 2, 8, 4, 16, 4, 4
     nb = b * mb + 1
-    kp = RNG.standard_normal((nb, bs, hkv, d)).astype(np.float32)
-    vp = RNG.standard_normal((nb, bs, hkv, d)).astype(np.float32)
+    kp = RNG.standard_normal((nb, bs, hkv * d)).astype(np.float32)
+    vp = RNG.standard_normal((nb, bs, hkv * d)).astype(np.float32)
     q = RNG.standard_normal((b, hq, d)).astype(np.float32)
     tables = 1 + RNG.permutation(b * mb).reshape(b, mb).astype(np.int32)
     lens = np.asarray([7, 13], np.int32)
@@ -238,6 +243,21 @@ def test_continuous_token_identical_to_seed_greedy(smoke_engine):
         ref_toks = eng.generate_drain([rq])[0].tokens   # seed path, batch=1
         assert len(res[i].tokens) == len(rq.prompt) + rq.max_new_tokens
         np.testing.assert_array_equal(res[i].tokens, ref_toks)
+
+
+def test_drained_rows_free_their_pools(smoke_engine):
+    """Each budget row's KV pools are freed when the row drains, so serving
+    row after row never holds two rows' pools at once (a dropped cache
+    lives on until the cyclic GC: its allocator's evict hook points back
+    at it)."""
+    eng = _mk_engine(smoke_engine, max_batch=2, max_len=32, block_size=4,
+                     prefill_chunk=8)
+    reqs = _mixed_requests(eng.cfg, [(5, 3, 0.4), (6, 3, 1.0), (4, 2, 0.4)])
+    eng.generate(reqs, mode="continuous")
+    nb = eng.max_batch * 32 // 4 + 1        # the pool's blocks, null included
+    pools = [a for a in jax.live_arrays() if a.ndim >= 4
+             and a.shape[1:3] == (nb, 4)]
+    assert not pools, [a.shape for a in pools]
 
 
 def test_budget_mapping_preserved(smoke_engine):
