@@ -49,3 +49,18 @@ def items(records, chunk: int):
 
 def between(its, lo: float, hi: float):
     return [it for it in its if lo <= it.time < hi]
+
+
+def keys(it: Item, window=None) -> int:
+    """Query-key pairs of the run: position ``p`` attends over ``p + 1``
+    keys, or the last ``window`` of them."""
+    if window is None:
+        return it.n * it.start + it.n * (it.n + 1) // 2
+    return sum(min(p + 1, window) for p in range(it.start, it.start + it.n))
+
+
+def context(it: Item, window=None) -> int:
+    """Rows of keys and values the run reads, each once: its whole context,
+    or from the first position's window on."""
+    first = 0 if window is None else max(0, it.start - window + 1)
+    return it.start + it.n - first

@@ -5,7 +5,7 @@ commit ends inside it): the gaps the engine itself opens between tokens,
 before the hand-off to the clients. Notes split the gaps by whether the
 iteration carried prompt chunks, and count the window iterations that
 compiled, rolled back or collected garbage."""
-LAYER, UNIT, MOVES = "engine", "ms", "itl_p95_ms"
+LAYER, UNIT, MOVES = "engine", "ms", "itl_p50_ms"
 
 
 def read(ctx):

@@ -9,6 +9,6 @@ def read(ctx):
     its = ctx.items_between(ctx.run.open, ctx.run.close)
     if not its:
         return None
-    flops = ctx.work("model").work(its, ctx.model, ctx.per_position_flops)
+    flops = ctx.work("model").work(its, ctx.shape, ctx.per_position_flops)
     seconds = ctx.run.close - ctx.run.open
     return 100.0 * flops / (seconds * ctx.peak["bf16_flops_per_s"])

@@ -10,11 +10,9 @@ no batching and no kernels. Each rank-``r`` linear is the truncated
 product ``x @ v[:, :r] @ u[:, :r].T`` of the full-rank factors, which the
 program's GAR deployment represents in another gauge.
 
-The model is the repo's dense block as the configuration states it:
-RMSNorm ``x * rsqrt(mean(x**2) + eps) * (1 + scale)``; q/k RMS head norms;
-RoPE over the whole head with ``base ** (-i / (D/2))`` on the two halves;
-causal softmax attention scaled by ``1/sqrt(D)``; SwiGLU FFN
-``down(silu(gate x) * up x)``; a tied or separate LM head.
+What is particular to a block (its groups, its weights and its forward)
+comes from the architecture module the configuration names
+(``bench/arch/<arch>.py``); what is here holds for every block.
 
 ``gaps`` compares served greedy tokens against this forward and
 ``topk_gaps`` served sampled tokens against its top-k; ``control_gaps``
@@ -24,59 +22,12 @@ do not run it.
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+import arch
 import weights
-
-HIGHEST = jax.lax.Precision.HIGHEST
-
-
-# ----------------------------------------------------------------- shapes
-
-def groups(conf: dict):
-    """(path, layers, d_out, d_in) of every factorised linear, in the
-    order the ladder's DP visits them: layer by layer, by name within a
-    layer (the order decides between groups of equal shape and curve)."""
-    m = conf["model"]
-    d, h, kv, f = m["d_model"], m["num_heads"], m["num_kv_heads"], m["d_ff"]
-    hd = d // h
-    per = m["layers_per_segment"]
-    out = []
-    for i in range(m["num_layers"] // per):
-        p = f"segments/{i}"
-        out += [(f"{p}/attn/k", per, kv * hd, d), (f"{p}/attn/o", per, d, h * hd),
-                (f"{p}/attn/q", per, h * hd, d), (f"{p}/attn/v", per, kv * hd, d),
-                (f"{p}/mlp/down", per, d, f), (f"{p}/mlp/gate", per, f, d),
-                (f"{p}/mlp/up", per, f, d)]
-    return out
-
-
-def leaves(conf: dict):
-    """Every weight as (path, shape, kind, arg) for ``weights.draw``."""
-    m = conf["model"]
-    d, v, hd = m["d_model"], m["vocab_size"], m["d_model"] // m["num_heads"]
-    per = m["layers_per_segment"]
-    power = conf["weights"]["singular_value_power"]
-    std = conf["weights"]["embed_std_times_sqrt_d"] / math.sqrt(d)
-    out = [("embed", (v, d), "normal", std), ("final_norm", (d,), "zeros", None)]
-    if not m["tie_embeddings"]:
-        out.append(("lm_head/w", (d, v), "normal", std))
-    for i in range(m["num_layers"] // per):
-        p = f"segments/{i}"
-        out += [(f"{p}/ln_attn", (per, d), "zeros", None),
-                (f"{p}/ln_mlp", (per, d), "zeros", None),
-                (f"{p}/attn/q_norm", (per, hd), "zeros", None),
-                (f"{p}/attn/k_norm", (per, hd), "zeros", None)]
-    for path, layers, d_out, d_in in groups(conf):
-        r = min(d_out, d_in)
-        scale = weights.factor_scale(r, d_out, power)
-        out += [(f"{path}/u", (layers, d_out, r), "factor", scale),
-                (f"{path}/v", (layers, d_in, r), "factor", scale)]
-    return out
 
 
 # ----------------------------------------------------------- the ladder
@@ -115,13 +66,14 @@ def ladder(conf: dict, max_frontier: int = 4096):
     budget."""
     power = conf["weights"]["singular_value_power"]
     levels = conf["flexrank"]["rank_levels"]
-    gs = groups(conf)
+    # (instances, d_out, d_in) of each group: its layers, times its experts
+    gs = [(int(np.prod(lead)), d_out, d_in)
+          for _, lead, d_out, d_in, _ in arch.module(conf).groups(conf)]
     cands, costs, full = [], [], []
-    for path, layers, d_out, d_in in gs:
+    for n, d_out, d_in in gs:
         r = min(d_out, d_in)
-        curve = weights.tail_curve(weights.singular_values(r, d_out, power),
-                                   layers)
-        cost = float((d_out + d_in) * layers)
+        curve = weights.tail_curve(weights.singular_values(r, d_out, power), n)
+        cost = float((d_out + d_in) * n)
         cands.append(_candidates(curve, cost, levels))
         costs.append(cost)
         full.append(r)
@@ -168,19 +120,21 @@ def ladder(conf: dict, max_frontier: int = 4096):
             seen.add(pick[2])
     table = np.asarray(sorted(rows, key=sum), np.int64)
     dense = sum(int(np.prod(s)) for _, s, _, _ in _dense_shapes(conf))
-    fact_full = sum(layers * d_out * d_in for _, layers, d_out, d_in in gs)
+    fact_full = sum(n * d_out * d_in for n, d_out, d_in in gs)
     deployed = np.asarray([
-        dense - fact_full + sum(layers * (d_out + d_in - r) * r
-                                for (_, layers, d_out, d_in), r in zip(gs, row))
+        dense - fact_full + sum(n * (d_out + d_in - r) * r
+                                for (n, d_out, d_in), r in zip(gs, row))
         for row in table], np.int64)
     return table, deployed
 
 
 def _dense_shapes(conf: dict):
-    """Every weight of the dense model (factor pairs as one matrix)."""
-    out = [x for x in leaves(conf) if x[2] != "factor"]
-    out += [(path, (layers, d_in, d_out), "dense", None)
-            for path, layers, d_out, d_in in groups(conf)]
+    """Every weight of the unfactorised model (a factor pair as one
+    matrix)."""
+    blk = arch.module(conf)
+    out = [x for x in blk.leaves(conf) if x[2] != "factor"]
+    out += [(path, tuple(lead) + (d_in, d_out), "dense", None)
+            for path, lead, d_out, d_in, _ in blk.groups(conf)]
     return out
 
 
@@ -192,74 +146,10 @@ def route(deployed, budget: float) -> int:
     return int(ok[-1]) if ok.size else 0
 
 
-# --------------------------------------------------------------- forward
-
-def _rms(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (y * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
-
-
-def _rope(x, pos, base):
-    half = x.shape[-1] // 2
-    freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos[:, None].astype(jnp.float32) * freq
-    sin, cos = jnp.sin(ang)[:, None, :], jnp.cos(ang)[:, None, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
-def forward(w: dict, ranks: dict, tokens, conf: dict, dtype=jnp.float32,
-            precision=HIGHEST):
-    """Logits (S, V) of one sequence, every position, in ``dtype`` with
-    matmuls at ``precision``."""
-    m = conf["model"]
-    d, h, kv = m["d_model"], m["num_heads"], m["num_kv_heads"]
-    hd, eps, per = d // h, m["norm_eps"], m["layers_per_segment"]
-    mm = lambda a, b: jnp.matmul(a, b, precision=precision)
-    cast = lambda a: a.astype(dtype)
-    s = tokens.shape[0]
-    pos = jnp.arange(s)
-    causal = pos[:, None] >= pos[None, :]
-
-    def lin(x, path, layer):
-        u, v = cast(w[f"{path}/u"][layer]), cast(w[f"{path}/v"][layer])
-        keep = cast(jnp.arange(u.shape[-1]) < ranks[path])
-        return mm(mm(x, v) * keep, u.T)
-
-    x = cast(w["embed"])[tokens]
-    for i in range(m["num_layers"] // per):
-        p = f"segments/{i}"
-        for l in range(per):
-            y = _rms(x, w[f"{p}/ln_attn"][l], eps)
-            q = _rms(lin(y, f"{p}/attn/q", l).reshape(s, h, hd),
-                     w[f"{p}/attn/q_norm"][l], eps)
-            k = _rms(lin(y, f"{p}/attn/k", l).reshape(s, kv, hd),
-                     w[f"{p}/attn/k_norm"][l], eps)
-            v = lin(y, f"{p}/attn/v", l).reshape(s, kv, hd)
-            q, k = _rope(q, pos, m["rope_base"]), _rope(k, pos, m["rope_base"])
-            k = jnp.repeat(k, h // kv, axis=1)
-            v = jnp.repeat(v, h // kv, axis=1)
-            logit = jnp.einsum("shd,thd->hst", q, k, precision=precision)
-            logit = logit.astype(jnp.float32) / math.sqrt(hd)
-            prob = jax.nn.softmax(jnp.where(causal, logit, -1e30), -1)
-            att = jnp.einsum("hst,thd->shd", cast(prob), v, precision=precision)
-            x = x + lin(att.reshape(s, h * hd), f"{p}/attn/o", l)
-            y = _rms(x, w[f"{p}/ln_mlp"][l], eps)
-            g = lin(y, f"{p}/mlp/gate", l)
-            x = x + lin(jax.nn.silu(g) * lin(y, f"{p}/mlp/up", l),
-                        f"{p}/mlp/down", l)
-    x = _rms(x, w["final_norm"], eps)
-    head = (cast(w["embed"]).T if m["tie_embeddings"]
-            else cast(w["lm_head/w"]))
-    return mm(x, head).astype(jnp.float32)
-
-
 def _readings(w, ranks, tokens, targets, *, conf, top_k):
     """Per position: the reference's best logit, its ``top_k``-th largest
     and its logit at ``targets`` (the next served token)."""
-    logits = forward(w, ranks, tokens, conf)
+    logits = arch.module(conf).forward(w, ranks, tokens, conf)
     picked = jnp.take_along_axis(logits, targets[:, None], -1)[:, 0]
     return logits.max(-1), jax.lax.top_k(logits, top_k)[0][:, -1], picked
 
@@ -274,9 +164,10 @@ CONTROLS = {"high": (jnp.float32, jax.lax.Precision.HIGH),
 def _control(w, ranks, tokens, *, conf, kind):
     """Per position: the reference's best logit and its logit at the token
     that the lower-precision forward ``kind`` puts first."""
-    ref = forward(w, ranks, tokens, conf)
+    fwd = arch.module(conf).forward
+    ref = fwd(w, ranks, tokens, conf)
     dtype, precision = CONTROLS[kind]
-    low = forward(w, ranks, tokens, conf, dtype=dtype, precision=precision)
+    low = fwd(w, ranks, tokens, conf, dtype=dtype, precision=precision)
     top = jnp.argmax(low, -1)
     return ref.max(-1), jnp.take_along_axis(ref, top[:, None], -1)[:, 0]
 
@@ -292,9 +183,10 @@ class Reference:
         table, deployed = ladder(conf)
         self.row = route(deployed, budget)
         self.deployed = int(deployed[self.row])
+        blk = arch.module(conf)
         self.ranks = {path: jnp.int32(r) for (path, *_), r
-                      in zip(groups(conf), table[self.row])}
-        self.w = weights.draw(seed, leaves(conf))
+                      in zip(blk.groups(conf), table[self.row])}
+        self.w = weights.draw(seed, blk.leaves(conf))
         fn = jax.jit(lambda w, r, t, g: _readings(w, r, t, g, conf=conf,
                                                   top_k=top_k))
         self._fn = fn

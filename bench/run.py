@@ -5,9 +5,12 @@
 A cell of ``BENCHMARK.json`` names a configuration (``bench/configs/``), a
 traffic mix (``bench/mixes/``) and has a file of its own
 (``bench/cells/<cell>.json``: the offered rate, the check's sample and
-limits). Per-layer metrics are readers in ``bench/metrics/<metric>.py``
-and kernel work counts in ``bench/work/<kernel>.py``: a later cell or
-metric adds files and entries.
+limits). A configuration names the module of its block
+(``bench/arch/<arch>.py``: its groups, weights, reference forward and
+attention shape). Per-layer metrics are readers in
+``bench/metrics/<metric>.py`` and kernel work counts in
+``bench/work/<kernel>.py``: a later cell, configuration or metric adds
+files and entries.
 
 Set-up (timed as ``setup_s``, from process start until the window opens):
 the FlexRank state is made on the device from the seed (``state.py``), the
@@ -68,6 +71,10 @@ def load_spec(root: str, workload: str) -> dict:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
     cell = cells[workload]
     config = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    conf = _json(os.path.join(root, config["file"]))
+    if "arch" not in conf:
+        raise ValueError(f"{config['file']} names no architecture: give it "
+                         "\"arch\", the name of its module in bench/arch/")
 
     def mine(metrics):
         return [m for m in metrics
@@ -75,7 +82,7 @@ def load_spec(root: str, workload: str) -> dict:
 
     return {
         "cell": cell,
-        "conf": _json(os.path.join(root, config["file"])),
+        "conf": conf,
         "mix": _json(os.path.join(HERE, "mixes", cell["traffic"] + ".json")),
         "cellfile": _json(os.path.join(HERE, "cells", workload + ".json")),
         "end_to_end": mine(bench["end_to_end"]),
@@ -155,19 +162,25 @@ def memory_peak(chips: int):
 
 class Context:
     """What the per-layer readers read: the clients' records, the engine's
-    counters, the trace reduction and the work counts."""
+    counters, the trace reduction and the work counts. ``shape`` is what
+    the work counts read of the model: the block's ``attention(conf)``,
+    ``d_model`` and ``vocab_size``."""
 
     def __init__(self, run, conf, peak, red, trace_pc, its, ranks):
+        import arch
         import e2e
-        import reference
-        self.run, self.conf, self.model = run, conf, conf["model"]
+        blk = arch.module(conf)
+        self.run, self.conf = run, conf
+        self.shape = dict(blk.attention(conf),
+                          d_model=conf["model"]["d_model"],
+                          vocab_size=conf["model"]["vocab_size"])
         self.peak, self.red, self.trace_pc = peak, red, trace_pc
         self.its = its
         self.pct = e2e.pct
         self.window_records = e2e.window_records(run)
         self._work = {}
         self.per_position_flops = load_module("work", "model").linear_flops(
-            reference.groups(conf), ranks)
+            blk.groups(conf), ranks)
         self.notes = {}
         self.trace_iterations = (
             sum(1 for t, *_ in run.metrics.steps
@@ -196,7 +209,7 @@ class Context:
         its = self.items_between(*self.trace_pc)
         if seconds <= 0.0 or not its:
             return None
-        flops, nbytes = self.work(kernel).work(its, self.model)
+        flops, nbytes = self.work(kernel).work(its, self.shape)
         share, bound = peaks.roofline_share(flops, nbytes, seconds, self.peak)
         self.notes[kernel] = bound
         return share
@@ -302,6 +315,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     """One run of one cell. Returns the result object (the last line).
     ``fault`` (tests only) is called with the engine before the window."""
     import jax
+    import arch
     import client
     import e2e
     import items as items_mod
@@ -403,7 +417,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
                      "idle_gaps": red["idle_gaps"]}
         table, deployed = reference.ladder(conf)
         ranks = {g[0]: int(r) for g, r in zip(
-            reference.groups(conf),
+            arch.module(conf).groups(conf),
             table[reference.route(deployed, mix["budget"])])}
         ctx = Context(run, conf, peaks.peaks(device["kind"]), red,
                       (marks["trace_lo"], marks["trace_hi"]),

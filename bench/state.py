@@ -15,30 +15,40 @@ import dataclasses
 
 import numpy as np
 
-from repro.configs.base import FlexRankConfig, ModelConfig, Segment
+from repro.configs.base import (FlexRankConfig, MLAConfig, ModelConfig,
+                                MoEConfig, Segment)
 from repro.core import flexrank as FR
 from repro.models import common as cm
 
 import weights
+from arch import dense
 
 
 def model_config(conf: dict) -> ModelConfig:
-    """The repo's ``ModelConfig`` as the configuration file states it."""
-    m = conf["model"]
-    per = m["layers_per_segment"]
-    segments = tuple(Segment("attn", per)
-                     for _ in range(m["num_layers"] // per))
+    """The repo's ``ModelConfig`` as the configuration file states it:
+    ``conf["model"]`` names its fields, ``segments`` is a list of
+    ``{kind, count}`` (or ``layers_per_segment``, that many ``attn``
+    layers a segment), ``moe`` and ``mla`` are dicts of ``MoEConfig`` and
+    ``MLAConfig``. ``family`` is ``moe`` where ``moe`` is stated and
+    ``dense`` otherwise, unless the file names it."""
+    m = dict(conf["model"])
+    m.pop("layers_per_segment", None)
+    m["segments"] = tuple(Segment(**s)
+                          for s in dense.segment_list(conf["model"]))
+    known = {f.name for f in dataclasses.fields(ModelConfig)}
+    unknown = sorted(set(m) - known)
+    if unknown:
+        raise ValueError(f"{conf['name']}: ModelConfig has no {unknown}")
+    for key, cls in (("moe", MoEConfig), ("mla", MLAConfig)):
+        if m.get(key) is not None:
+            m[key] = cls(**m[key])
+    m.setdefault("family", "moe" if m.get("moe") else "dense")
     fr = conf["flexrank"]
     return ModelConfig(
-        name=conf["name"], family="dense", num_layers=m["num_layers"],
-        d_model=m["d_model"], num_heads=m["num_heads"],
-        num_kv_heads=m["num_kv_heads"], d_ff=m["d_ff"],
-        vocab_size=m["vocab_size"], segments=segments,
-        rope_base=m["rope_base"], norm_eps=m["norm_eps"],
-        tie_embeddings=m["tie_embeddings"], max_seq_len=m["max_seq_len"],
+        name=conf["name"], source=conf["source"],
         flexrank=FlexRankConfig(enabled=True, budgets=tuple(fr["budgets"]),
                                 rank_levels=fr["rank_levels"]),
-        source=conf["source"])
+        **m)
 
 
 def _leaves(tree, prefix=""):

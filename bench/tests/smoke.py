@@ -1,43 +1,59 @@
-"""A cell at smoke size for the CPU tests: the repo's smoke widths of
-gpt2 (2 layers, d64, 4 heads, vocab 512), short requests, a short window."""
+"""A cell at smoke size for the CPU tests: a configuration of
+``BENCHMARK.json`` at its architecture module's smoke widths, short
+requests, a short window."""
 from __future__ import annotations
 
 import copy
 import json
 import os
 
+import arch
+
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
 
 
-def _json(name):
-    with open(os.path.join(BENCH, name)) as f:
+def _json(path):
+    with open(path) as f:
         return json.load(f)
 
 
-def conf(tie: bool = True) -> dict:
-    c = _json("configs/gpt2-small.json")
-    c["name"] = "gpt2-smoke"
-    c["model"].update(num_layers=2, d_model=64, num_heads=4, num_kv_heads=4,
-                      d_ff=128, vocab_size=512, layers_per_segment=1,
-                      tie_embeddings=tie, max_seq_len=256)
+BENCHMARK = _json(os.path.join(ROOT, "BENCHMARK.json"))
+# the configurations that tests "of every configuration" run
+CONFIGS = [c["name"] for c in BENCHMARK["configs"]]
+
+
+def full(name: str) -> dict:
+    """A configuration of ``BENCHMARK.json`` as its file states it."""
+    c = {c["name"]: c for c in BENCHMARK["configs"]}[name]
+    return _json(os.path.join(ROOT, c["file"]))
+
+
+def conf(name: str = CONFIGS[0], tie: bool = True) -> dict:
+    """``name`` at smoke size, with a small engine."""
+    c = arch.module(full(name)).smoke(full(name))
+    c["model"]["tie_embeddings"] = tie
     c["engine"].update(max_batch=4, max_len=64, num_blocks=16,
                        prefill_chunk=8)
     return c
 
 
 def mix() -> dict:
-    m = copy.deepcopy(_json("mixes/chat.json"))
+    m = copy.deepcopy(_json(os.path.join(BENCH, "mixes", "chat.json")))
     m["prompt_tokens"].update(median=12, min=4, max=40)
     m["output_tokens"].update(median=6, min=2, max=16)
     m.update(lead_in_s=0.5, tail_s=1.0, greedy_share=0.5, burst=2)
     return m
 
 
-def spec(rate: float = 4.0, limit: float = 1e-3) -> dict:
+def spec(rate: float = 4.0, limit: float = 1e-3, config: dict = None) -> dict:
+    """The smoke cell on ``config`` (the first configuration at smoke size
+    where none is given)."""
+    c = config or conf()
     return {
-        "cell": {"name": "gpt2-smoke.chat", "config": "gpt2-smoke",
+        "cell": {"name": c["name"] + ".chat", "config": c["name"],
                  "traffic": "chat", "chips": 1},
-        "conf": conf(), "mix": mix(),
+        "conf": c, "mix": mix(),
         "cellfile": {"rate_per_s": rate,
                      "check": {"requests": 3, "greedy_gap_limit": limit,
                                "topk_gap_limit": limit}},

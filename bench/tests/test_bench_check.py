@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import arch
 import reference
 import run
 import smoke
@@ -29,7 +30,7 @@ def _control_decode(ref, prompt, new, kind):
     """Greedy decoding by the reference's forward at the control's
     precision: the tokens a lower-precision program would serve."""
     dtype, precision = reference.CONTROLS[kind]
-    fwd = jax.jit(lambda w, r, t: reference.forward(
+    fwd = jax.jit(lambda w, r, t: arch.module(ref.conf).forward(
         w, r, t, ref.conf, dtype=dtype, precision=precision))
     seq = list(prompt)
     for _ in range(new):

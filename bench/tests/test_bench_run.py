@@ -20,12 +20,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 SEED = 2 ** 33 + 7
 
 
-def _spec():
-    return smoke.spec()
+def _spec(name=smoke.CONFIGS[0]):
+    return smoke.spec(config=smoke.conf(name))
 
 
-def test_a_whole_run_prints_the_contracts_last_line():
-    res = run.run_cell(_spec(), SEED, 1.0, False, require_chip=False,
+@pytest.mark.parametrize("name", smoke.CONFIGS)
+def test_a_whole_run_prints_the_contracts_last_line(name):
+    res = run.run_cell(_spec(name), SEED, 1.0, False, require_chip=False,
                        use_pallas="interpret", log=lambda s: None)
     line = json.loads(json.dumps(res))
     assert list(line)[:5] == ["correct", "attempted", "failed", "metrics",
@@ -59,8 +60,9 @@ def _alter_tokens(engine):
     engine._sample_jit = altered
 
 
-def test_a_token_altered_where_it_is_produced_is_not_correct():
-    res = run.run_cell(_spec(), SEED, 1.0, False, require_chip=False,
+@pytest.mark.parametrize("name", smoke.CONFIGS)
+def test_a_token_altered_where_it_is_produced_is_not_correct(name):
+    res = run.run_cell(_spec(name), SEED, 1.0, False, require_chip=False,
                        use_pallas=False, fault=_alter_tokens,
                        log=lambda s: None)
     assert res["correct"] is False
@@ -87,8 +89,9 @@ def _no_topk(engine):
     engine._pack_sampling = loose
 
 
-def test_a_sampled_token_outside_the_top_k_is_not_correct():
-    res = run.run_cell(_spec(), SEED, 1.0, False, require_chip=False,
+@pytest.mark.parametrize("name", smoke.CONFIGS)
+def test_a_sampled_token_outside_the_top_k_is_not_correct(name):
+    res = run.run_cell(_spec(name), SEED, 1.0, False, require_chip=False,
                        use_pallas=False, fault=_no_topk, log=lambda s: None)
     assert res["correct"] is False
     assert res["check"]["topk_gap"]["value"] > \
@@ -97,8 +100,9 @@ def test_a_sampled_token_outside_the_top_k_is_not_correct():
         res["check"]["greedy_gap"]["limit"]
 
 
-def test_a_request_routed_to_the_wrong_row_is_not_correct():
-    res = run.run_cell(_spec(), SEED, 1.0, False, require_chip=False,
+@pytest.mark.parametrize("name", smoke.CONFIGS)
+def test_a_request_routed_to_the_wrong_row_is_not_correct(name):
+    res = run.run_cell(_spec(name), SEED, 1.0, False, require_chip=False,
                        use_pallas=False, fault=_wrong_row, log=lambda s: None)
     assert res["correct"] is False
 
@@ -118,8 +122,9 @@ def _fake_trace(run_obj, marks):
     return xplane.clip({"device": {"/device:TPU:0": dev}, "host": host})
 
 
-def test_a_traced_run_reports_every_per_layer_metric(monkeypatch):
-    sp = _spec()
+@pytest.mark.parametrize("name", smoke.CONFIGS)
+def test_a_traced_run_reports_every_per_layer_metric(monkeypatch, name):
+    sp = _spec(name)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         sp["per_layer"] = json.load(f)["per_layer"]
     seen = {}
@@ -139,6 +144,10 @@ def test_a_traced_run_reports_every_per_layer_metric(monkeypatch):
     import jax
 
     class Ann:
+        # the program's scopes stay off, as with no profiler session, in
+        # whichever order the tests run
+        is_enabled = staticmethod(lambda: False)
+
         def __init__(self, name):
             pass
 
@@ -168,23 +177,26 @@ def test_a_traced_run_reports_every_per_layer_metric(monkeypatch):
 
 def test_without_a_chip_the_command_exits_3_and_prints_no_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    p = subprocess.run([sys.executable, "bench/run.py", "--workload",
-                        "gpt2-small.chat", "--seed", "1", "--seconds", "1"],
+    cell = smoke.BENCHMARK["workloads"][0]["name"]
+    p = subprocess.run([sys.executable, "bench/run.py", "--workload", cell,
+                        "--seed", "1", "--seconds", "1"],
                        cwd=ROOT, env=env, capture_output=True, text=True,
                        timeout=120)
     assert p.returncode == 3
     assert not any(line.startswith("{") for line in p.stdout.splitlines())
 
 
-def test_the_engine_serves_the_seeded_state_as_the_reference_computes():
+@pytest.mark.parametrize("name", smoke.CONFIGS)
+def test_the_engine_serves_the_seeded_state_as_the_reference_computes(name):
     """Greedy requests through ``generate()`` with the interpret kernels:
     every served token is the reference's first choice at float32."""
     from repro.serving import Request
-    sp = _spec()
+    sp = _spec(name)
     engine, row, params = run.build(sp, SEED, use_pallas="interpret",
                                     log=lambda s: None)
     rng = np.random.default_rng(0)
-    reqs = [Request(prompt=rng.integers(0, 512, n).astype(np.int32),
+    vocab = sp["conf"]["model"]["vocab_size"]
+    reqs = [Request(prompt=rng.integers(0, vocab, n).astype(np.int32),
                     max_new_tokens=k, budget=sp["mix"]["budget"])
             for n, k in ((5, 9), (17, 6), (30, 12), (9, 3))]
     results = engine.generate(reqs, mode="continuous")
@@ -196,16 +208,14 @@ def test_the_engine_serves_the_seeded_state_as_the_reference_computes():
         assert ref.gaps(rq.prompt, served).max() == 0.0
 
 
-def test_the_ladder_is_the_programs_own():
+@pytest.mark.parametrize("name", smoke.CONFIGS)
+def test_the_ladder_is_the_programs_own(name):
     """The reference's ladder (its own copy of the DP and the router's
-    rule) gives the program's table and deployed counts, at smoke size and
-    at both configurations' full widths."""
+    rule) gives the program's table and deployed counts, at smoke size
+    (tied and untied) and at the configuration's full widths."""
     from repro.core import flexrank as FR
     import copy
-    confs = [smoke.conf(), smoke.conf(tie=False)]
-    for name in ("gpt2-small",):
-        with open(os.path.join(ROOT, "bench", "configs", name + ".json")) as f:
-            confs.append(json.load(f))
+    confs = [smoke.conf(name), smoke.conf(name, tie=False), smoke.full(name)]
     for c in confs:
         cfg = state.model_config(copy.deepcopy(c))
         table, infos = FR.build_table(cfg, state.curves(cfg, c))

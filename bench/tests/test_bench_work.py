@@ -6,6 +6,7 @@ import os
 import numpy as np
 import pytest
 
+import arch
 import peaks
 import reference
 from items import Item, items
@@ -25,54 +26,84 @@ def _work(name):
 ITEMS = [Item(0.0, 0, 8, False), Item(0.1, 8, 5, True),
          Item(0.2, 13, 1, True), Item(0.3, 40, 1, True)]
 
+# the smoke configuration's shape, and one with windowed layers and keys
+# and values of different widths read from one latent row of the keys'
+# width (as absorbed latent attention has)
+SHAPES = {
+    "smoke": None,
+    "windowed": {"heads": 4, "kv_heads": 1, "qk_dim": 24, "v_dim": 16,
+                 "kv_width": 24,
+                 "windows": [6, None, 6, 3], "d_model": 64,
+                 "vocab_size": 512},
+}
 
-def test_attention_work_matches_a_brute_force_count():
-    m = conf()["model"]
-    h, kv, hd = m["num_heads"], m["num_kv_heads"], m["d_model"] // m["num_heads"]
+
+def _shape(name):
+    if SHAPES[name] is not None:
+        return SHAPES[name]
+    c = conf()
+    return dict(arch.module(c).attention(c), d_model=c["model"]["d_model"],
+                vocab_size=c["model"]["vocab_size"])
+
+
+def _attended(it, window):
+    """Every (position, key) pair of the run, one by one."""
+    for p in range(it.start, it.start + it.n):
+        for key in range(p + 1):
+            if window is None or p - key < window:
+                yield p, key
+
+
+@pytest.mark.parametrize("name", SHAPES)
+def test_attention_work_matches_a_brute_force_count(name):
+    sh = _shape(name)
+    h, kv, dqk, dv = sh["heads"], sh["kv_heads"], sh["qk_dim"], sh["v_dim"]
     flops = nbytes = 0
-    for it in ITEMS:
-        kv_rows = set()
-        for p in range(it.start, it.start + it.n):
-            for key in range(p + 1):          # every (query, key) pair
-                flops += h * hd * 2 * 2        # QK^T and PV, mul + add
+    for window in sh["windows"]:
+        for it in ITEMS:
+            kv_rows = set()
+            for p, key in _attended(it, window):
+                flops += h * (dqk + dv) * 2    # QK^T and PV, mul + add
                 kv_rows.add(key)
-            nbytes += 2 * h * hd * 4           # the query in, its output out
-        nbytes += len(kv_rows) * 2 * kv * hd * 4
-    f, b = _work("paged_prefill_attention").work(ITEMS, m)
-    assert f == flops * m["num_layers"]
-    assert b == nbytes * m["num_layers"]
+            nbytes += it.n * h * (dqk + dv) * 4      # queries in, outputs out
+            nbytes += len(kv_rows) * kv * sh["kv_width"] * 4
+    f, b = _work("paged_prefill_attention").work(ITEMS, sh)
+    assert f == flops
+    assert b == nbytes
 
 
 def test_sampling_work_matches_a_brute_force_count():
-    m = conf()["model"]
+    sh = _shape("smoke")
     rows = [it for it in ITEMS if it.sampled]
-    f, b = _work("topk_mask_sample").work(ITEMS, m)
-    assert b == sum(m["vocab_size"] * 4 + 4 for _ in rows)
-    assert f == sum(4 * m["vocab_size"] for _ in rows)
+    f, b = _work("topk_mask_sample").work(ITEMS, sh)
+    assert b == sum(sh["vocab_size"] * 4 + 4 for _ in rows)
+    assert f == sum(4 * sh["vocab_size"] for _ in rows)
 
 
-def test_model_flops_match_a_brute_force_count():
+@pytest.mark.parametrize("name", SHAPES)
+def test_model_flops_match_a_brute_force_count(name):
     c = conf()
-    m = c["model"]
     table, deployed = reference.ladder(c)
     row = table[reference.route(deployed, 0.85)]
-    groups = reference.groups(c)
+    groups = arch.module(c).groups(c)
     ranks = {g[0]: int(r) for g, r in zip(groups, row)}
     wm = _work("model")
     per = 0
-    for path, layers, d_out, d_in in groups:
+    for path, lead, d_out, d_in, per_position in groups:
         r = ranks[path]
         # GAR: z = x @ v_tilde (d_in x r), tail = z @ u_hat.T ((d_out - r) x r)
-        per += layers * 2 * (d_in * r + (d_out - r) * r)
+        per += per_position * 2 * (d_in * r + (d_out - r) * r)
     assert wm.linear_flops(groups, ranks) == per
-    hd = m["d_model"] // m["num_heads"]
+    sh = _shape(name)
     brute = 0
     for it in ITEMS:
-        for p in range(it.start, it.start + it.n):
-            brute += per + m["num_layers"] * 4 * m["num_heads"] * hd * (p + 1)
+        for window in sh["windows"]:
+            for _ in _attended(it, window):
+                brute += 2 * sh["heads"] * (sh["qk_dim"] + sh["v_dim"])
+        brute += per * it.n
         if it.sampled:
-            brute += 2 * m["d_model"] * m["vocab_size"]
-    assert wm.work(ITEMS, m, per) == brute
+            brute += 2 * sh["d_model"] * sh["vocab_size"]
+    assert wm.work(ITEMS, sh, per) == brute
 
 
 def test_items_follow_the_clients_tokens():

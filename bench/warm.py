@@ -1,21 +1,15 @@
 """Warm the program shapes a cell can reach, before the window.
 
-The engine compiles one fused step program per (token width, block-table
-width, sample rows, top-k or not) and one feed-fixup program per
-(token width, fixups, previous sample rows). Its bucketing keeps these
+The engine compiles one fused step program per (token width, sample rows)
+and one feed-fixup program per (token width, fixups, previous sample
+rows). Every step passes block tables ``padded_max_blocks`` wide and a
+``top_k`` array (zeros where a row does not truncate), so neither the table
+width nor top-k multiplies the step programs. Its bucketing keeps these
 sets finite; this module enumerates them by applying the engine's own
-bucketing (``_bucket_tokens``, ``_bucket_rows``; the KV cache's table
-widths, powers of two capped at its ``padded_max_blocks``) to every token
-count, sample count and sequence length the settings admit.
-
-Every fixup shape is warmed (each compiles in well under a second). Step
-programs take seconds each to compile and to load from the cache, so only
-those a loaded server meets are warmed: every token width and row bucket
-at tables at least half the widest, and the ramp's chunk-wide iterations
-(at least ``prefill_chunk`` tokens, few sampled rows, top-k on or off)
-at every table. What is left needs a server all but empty, every live
-sequence short or every sampled row greedy; a run prints the compiles its
-window saw.
+bucketing (``_bucket_tokens``, ``_bucket_rows``) to every token count and
+sample count the settings admit, and warms every one of them: nothing
+that a run can dispatch is left to compile in its window, and a run
+prints the compiles its window saw.
 
 Each program is lowered on this thread and compiled on a few others (XLA
 compiles with the interpreter lock released; a bounded number in flight
@@ -33,48 +27,23 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def table_widths(cache):
-    """The KV cache's table-width rule (``active_max_blocks``) over every
-    sequence length: smallest power of two >= its blocks, capped."""
-    out = set()
-    for blocks in range(1, cache.max_blocks_per_seq + 1):
-        t = 1
-        while t < blocks:
-            t *= 2
-        out.add(min(t, cache.padded_max_blocks))
-    return sorted(out)
-
-
 def shapes(engine, cache):
     """(step shapes, fixup shapes) to warm.
 
-    Step: (token width, table width, sample rows, top-k). Rows come from at
-    most ``min(tokens, max_batch)`` sampled positions (decode slots and
-    finishing chunks, one slot each), zero included; top-k is off whenever
-    every sampled row is greedy. Fixup: (token width, fixups, previous
+    Step: (token width, table width, sample rows). Rows come from at most
+    ``min(tokens, max_batch)`` sampled positions (decode slots and
+    finishing chunks, one slot each), zero included; the table is always
+    ``padded_max_blocks`` wide. Fixup: (token width, fixups, previous
     rows), a fixup patching one continuing decode slot from the previous
     iteration's sampled rows."""
     mb = engine.max_batch
-    pairs, rows_all = set(), set()
+    pairs = set()
     for used in range(1, engine._mixed_budget + 1):
         w = engine._bucket_tokens(used)
         for n in range(0, min(used, mb) + 1):
-            r = engine._bucket_rows(n)
-            pairs.add((w, r, min(used, mb)))
-            rows_all.add(r)
-    wide = cache.padded_max_blocks // 2
-    ramp_width = engine._bucket_tokens(engine.prefill_chunk)
-    rows_low = sorted(rows_all)[:2]
-
-    def kept(w, t, r, topk):
-        ramp = w >= ramp_width and r <= rows_low[-1]
-        if not topk:
-            return ramp and r == rows_low[0]
-        return t >= wide or ramp
-
-    step = sorted({(w, t, r, topk) for w, r, _ in pairs
-                   for t in table_widths(cache) for topk in (True, False)
-                   if kept(w, t, r, topk)})
+            pairs.add((w, engine._bucket_rows(n), min(used, mb)))
+    step = sorted({(w, cache.padded_max_blocks, r) for w, r, _ in pairs})
+    rows_all = {r for _, r, _ in pairs}
     most = {}
     for w, _, n in pairs:
         most[w] = max(most.get(w, 0), n)
@@ -83,14 +52,14 @@ def shapes(engine, cache):
     return step, fixup
 
 
-def _sampling(rows: int, topk: bool):
+def _sampling(rows: int):
     z = jnp.zeros(rows, jnp.int32)
     return {"temperature": jnp.asarray(np.full(rows, 0.8, np.float32)),
-            "top_k": jnp.asarray(np.full(rows, 40, np.int32)) if topk else None,
+            "top_k": jnp.asarray(np.full(rows, 40, np.int32)),
             "seed": z, "req_id": z, "purpose": z, "position": z}
 
 
-def _step_args(engine, params, pools, w, t, r, topk):
+def _step_args(engine, params, pools, w, t, r):
     mb = engine.max_batch
     caches = {
         "slot_ids": jnp.asarray(np.full(w, mb, np.int32)),   # the null slot
@@ -99,7 +68,7 @@ def _step_args(engine, params, pools, w, t, r, topk):
         "segments": pools,
         "sample_ids": jnp.asarray(np.zeros(r, np.int32)),
     }
-    return (params, caches, np.zeros((1, w), np.int32), _sampling(r, topk))
+    return (params, caches, np.zeros((1, w), np.int32), _sampling(r))
 
 
 def _fixup_args(w, f, r):

@@ -39,13 +39,14 @@ def singular_values(full_rank: int, d_out: int, power: float) -> np.ndarray:
     return s * math.sqrt(d_out / float(np.sum(s * s)))
 
 
-def tail_curve(s: np.ndarray, layers: int) -> np.ndarray:
+def tail_curve(s: np.ndarray, instances: int) -> np.ndarray:
     """``curve[r - 1]``: energy left out when keeping rank r, summed over
-    the group's layers (the DP's probe error as ``FR.decompose`` defines
-    it, here in closed form from the profile)."""
+    the group's ``instances`` (its layers, times its experts in an expert
+    stack): the DP's probe error as ``FR.decompose`` defines it, here in
+    closed form from the profile."""
     sq = s * s
     tail = np.concatenate([np.cumsum(sq[::-1])[::-1][1:], [0.0]])
-    return tail * layers
+    return tail * instances
 
 
 def factor_scale(full_rank: int, d_out: int, power: float) -> np.ndarray:

@@ -1,30 +1,37 @@
-"""Model flops of the served row: each position through every layer and
-each sampled position through the LM head.
+"""Model flops of the served row: each position through every group and
+every layer that attends, and each sampled position through the LM head.
 
-Per layer and position: the GAR linears at the row's ranks,
-``2 * (d_out + d_in - r) * r`` each (the identity block is neither stored
-nor multiplied), and attention over ``p + 1`` keys,
-``4 * heads * head_dim * (p + 1)``. Per sampled position: the LM head,
-``2 * d_model * vocab``. Norms, RoPE and softmax are left out.
+Per position: each GAR group at the row's rank, ``2 * (d_out + d_in - r)
+* r`` (the identity block is neither stored nor multiplied) times the
+instances of the group a position passes through (``per_position``: its
+layers, times ``top_k`` for an expert stack); attention in each layer
+that attends, over ``p + 1`` keys or its window, ``2 * heads * (qk_dim +
+v_dim)`` a key. Per sampled position: the LM head, ``2 * d_model *
+vocab``. Norms, RoPE, softmax and routers are left out.
 """
 from __future__ import annotations
 
+from collections import Counter
+
+import items
+
 
 def linear_flops(groups, ranks) -> float:
-    """Per position, summed over layers: ``groups`` are the reference's
-    (path, layers, d_out, d_in), ``ranks`` the row's rank per path."""
-    return sum(2.0 * layers * (d_out + d_in - ranks[path]) * ranks[path]
-               for path, layers, d_out, d_in in groups)
+    """Per position: ``groups`` are the architecture module's (path,
+    lead_shape, d_out, d_in, per_position), ``ranks`` the row's rank per
+    path."""
+    return sum(2.0 * (d_out + d_in - ranks[path]) * ranks[path] * per_position
+               for path, _, d_out, d_in, per_position in groups)
 
 
-def work(its, model: dict, per_position: float) -> float:
-    h = model["num_heads"]
-    hd = model["d_model"] // h
-    layers = model["num_layers"]
+def work(its, shape: dict, per_position: float) -> float:
+    per_key = 2.0 * shape["heads"] * (shape["qk_dim"] + shape["v_dim"])
+    layers = Counter(shape["windows"])
     flops = 0.0
     for it in its:
-        keys = it.n * it.start + it.n * (it.n + 1) // 2
-        flops += per_position * it.n + 4.0 * h * hd * keys * layers
+        attention = sum(per_key * items.keys(it, window) * n
+                        for window, n in layers.items())
+        flops += per_position * it.n + attention
         if it.sampled:
-            flops += 2.0 * model["d_model"] * model["vocab_size"]
+            flops += 2.0 * shape["d_model"] * shape["vocab_size"]
     return flops

@@ -5,7 +5,7 @@ running sum (4 flops), and one token written back."""
 from __future__ import annotations
 
 
-def work(its, model: dict):
+def work(its, shape: dict):
     rows = sum(1 for it in its if it.sampled)
-    v = model["vocab_size"]
+    v = shape["vocab_size"]
     return 4.0 * v * rows, rows * (4.0 * v + 4.0)
